@@ -57,6 +57,8 @@ def _write_json(path="BENCH_sssp.json"):
 
 def main() -> None:
     only = sys.argv[1] if len(sys.argv) > 1 else None
+    from repro.runtime import enable_compile_cache
+    enable_compile_cache()
 
     from benchmarks import sssp_bench, kernel_bench
     if only in (None, "sssp"):

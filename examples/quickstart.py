@@ -117,8 +117,8 @@ def main():
     #    relax kernel settles each shard, the slot-tiled send kernel packs
     #    the payload, the msg-tiled merge kernel scatters incoming — over
     #    layouts build_shards precomputed (tx_*/mx_* next to rx_*).
-    #    Interpret mode runs the kernels on CPU; pallas_interpret=False on
-    #    real TPUs. Bit-identical to the XLA backends.
+    #    On the CPU the kernels run in interpret mode; on a TPU they are
+    #    compiled, never interpreted. Bit-identical to the XLA backends.
     kengine = SsspEngine.build(shards, SsspConfig(
         local_solver="pallas", send_backend="pallas", merge_backend="pallas",
         toka="toka2"))

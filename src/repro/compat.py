@@ -1,53 +1,16 @@
-"""Version compatibility for the jax APIs this repo straddles.
+"""Mesh construction with the axis types this repo relies on.
 
-The codebase targets the current jax API (``jax.shard_map``,
-``jax.make_mesh(..., axis_types=...)``, ``check_vma``); older releases
-(<= 0.4.x) ship the same functionality as ``jax.experimental.shard_map``
-(with ``check_rep``) and a ``make_mesh`` without ``axis_types``. Everything
-runtime-critical goes through these two wrappers so a single interpreter
-can run either jax.
+``make_mesh`` keeps every axis ``Auto`` (jax's default is ``Explicit``),
+so sharding inside jit is left to the compiler as the solver expects.
 """
 from __future__ import annotations
 
 import jax
 
-try:  # jax >= 0.5
-    _shard_map = jax.shard_map
-    _CHECK_KW = "check_vma"
-except AttributeError:
-    from jax.experimental.shard_map import shard_map as _shard_map
-    _CHECK_KW = "check_rep"
 
-
-def shard_map(f, *, mesh=None, in_specs, out_specs,
-              check_vma: bool | None = None):
-    kwargs = {} if check_vma is None else {_CHECK_KW: check_vma}
-    if mesh is None and _CHECK_KW == "check_rep":
-        # old shard_map cannot infer the mesh from context — resolve it here
-        from jax._src.mesh import thread_resources
-        mesh = thread_resources.env.physical_mesh
-        if mesh.empty:
-            raise ValueError("shard_map without mesh requires an active "
-                             "mesh context (compat.set_mesh)")
-    if mesh is not None:
-        kwargs["mesh"] = mesh
-    return _shard_map(f, in_specs=in_specs, out_specs=out_specs, **kwargs)
-
-
-def set_mesh(mesh):
-    """``jax.set_mesh`` context; falls back to the legacy ``with mesh:``
-    resource context on older jax."""
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)
-    if hasattr(jax.sharding, "use_mesh"):
-        return jax.sharding.use_mesh(mesh)
-    return mesh  # jax.sharding.Mesh is itself a context manager
-
-
-def make_mesh(axis_shapes, axis_names, *, auto_axes: bool = True):
-    """``jax.make_mesh`` with Auto axis_types where supported."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None or not auto_axes:
-        return jax.make_mesh(axis_shapes, axis_names)
-    return jax.make_mesh(axis_shapes, axis_names,
-                         axis_types=(axis_type.Auto,) * len(axis_names))
+def make_mesh(axis_shapes, axis_names, *, devices=None):
+    """``jax.make_mesh`` with Auto axis types."""
+    return jax.make_mesh(
+        axis_shapes, axis_names,
+        axis_types=(jax.sharding.AxisType.Auto,) * len(axis_names),
+        devices=devices)

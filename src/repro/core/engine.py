@@ -50,6 +50,8 @@ import time
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding
+from jax.sharding import PartitionSpec as P
 
 from repro.core import phases
 from repro.core.shards import SsspShards, build_shards, shard_distance_rows
@@ -170,6 +172,12 @@ class SsspEngine:
                              "['shmap', 'sim']")
         if backend == "shmap" and (mesh is None or axis_names is None):
             raise ValueError("backend='shmap' requires mesh and axis_names")
+        if backend == "shmap" and shards.n_parts != mesh.size:
+            # each device solves x[0] of its block: any other count would
+            # silently drop shards (or leave devices without one)
+            raise ValueError(
+                f"backend='shmap' needs one shard per device: n_parts="
+                f"{shards.n_parts} but the mesh has {mesh.size} devices")
         self.shards = shards
         self.cfg = cfg
         self.backend = backend
@@ -218,32 +226,51 @@ class SsspEngine:
         self.cert_traces = 0
         self._cert_shmap = None     # lazily built shmap certificate
         if backend == "sim":
-            base_round = _make_round(shards, cfg, SimComm(shards.n_parts),
-                                     vmapped=True, n_parts=shards.n_parts)
+            # the shards are a jit ARGUMENT of every sim program, never a
+            # closure: a captured array is embedded in the program as a
+            # constant, which at real graph sizes bloats compilation
+            comm = SimComm(shards.n_parts)
+            n_parts = shards.n_parts
 
-            def counted_round(carry):
+            def counted_round(sh, carry):
                 self._note_trace(int(carry.dist.shape[1]))
-                return base_round(carry)
+                return _make_round(sh, cfg, comm, vmapped=True,
+                                   n_parts=n_parts)(carry)
 
-            def counted_cert(dist_pk):
+            def counted_cert(sh, dist_pk):
                 self.cert_traces += 1
-                return certificate_improved_sim(shards, dist_pk)
+                return certificate_improved_sim(sh, dist_pk)
 
             self.round_fn = jax.jit(counted_round)
             self._cert_fn = jax.jit(counted_cert)
             # fused round / deferred (async) exchange: the loop can exit
             # with delivered-but-unmerged messages in carry.incoming and
             # undelivered payload in carry.inflight (see sssp.make_finalize)
-            fin = make_finalize(shards, cfg, SimComm(shards.n_parts),
-                                vmapped=True)
-            self._finalize_fn = jax.jit(fin) if fin is not None else None
+            if make_finalize(shards, cfg, comm, vmapped=True) is not None:
+                self._finalize_fn = jax.jit(
+                    lambda sh, carry: make_finalize(sh, cfg, comm,
+                                                    vmapped=True)(carry))
+            else:
+                self._finalize_fn = None
             self.shmap_solver = None
         else:
+            # place the stack once, one shard per device; every solve then
+            # passes arrays already laid out as the program's in_specs
+            placed = NamedSharding(mesh, P(self.axis_names))
+
+            def place(x):
+                if isinstance(x, jax.ShapeDtypeStruct):   # shape-only shards
+                    return jax.ShapeDtypeStruct(x.shape, x.dtype,
+                                                sharding=placed)
+                return jax.device_put(x, placed)
+
+            self.shards = jax.tree_util.tree_map(place, shards)
             self.round_fn = None
             self._cert_fn = None
             self._finalize_fn = None
             self.shmap_solver = build_shmap_solver_traced(
-                shards, cfg, mesh, self.axis_names, on_trace=self._note_trace)
+                self.shards, cfg, mesh, self.axis_names,
+                on_trace=self._note_trace)
 
     # ---------------------------------------------------------- build ----
 
@@ -352,7 +379,7 @@ class SsspEngine:
             while r < self.cfg.max_rounds:
                 fresh = self.trace_count == traces_loop
                 tc = time.perf_counter()
-                carry = self.round_fn(carry)
+                carry = self.round_fn(self.shards, carry)
                 if fresh and self.trace_count > traces_loop:
                     jax.block_until_ready(carry)
                     compile_s += time.perf_counter() - tc
@@ -361,27 +388,29 @@ class SsspEngine:
                     break
             dist_pk = carry.dist
             if self._finalize_fn is not None:
-                dist_pk = self._finalize_fn(carry)
+                dist_pk = self._finalize_fn(self.shards, carry)
             done_k = np.asarray(carry.done)[0][:k]  # globally agreed
             # [P, K, block] -> per-query global distance vectors
             dist = np.moveaxis(np.asarray(dist_pk), 0, 1)
             dist = dist.reshape(kb, -1)[:k, : self.shards.n_vertices]
+            # host sums in int64: a K=16 batch at real graph sizes comes
+            # near 2**31 relaxations
             stats = SsspStats(
                 rounds=carry.rounds,
-                relaxations=np.sum(carry.relaxations, dtype=np.int32),
-                msgs_sent=np.sum(carry.msgs_sent, dtype=np.int32),
-                msgs_recv=np.sum(carry.msgs_recv, dtype=np.int32),
-                pruned_edges=np.sum(carry.pruned, dtype=np.int32),
+                relaxations=np.sum(np.asarray(carry.relaxations), dtype=np.int64),
+                msgs_sent=np.sum(np.asarray(carry.msgs_sent), dtype=np.int64),
+                msgs_recv=np.sum(np.asarray(carry.msgs_recv), dtype=np.int64),
+                pruned_edges=np.sum(np.asarray(carry.pruned), dtype=np.int64),
                 q_rounds=np.max(np.asarray(carry.q_rounds), axis=0)[:k],
                 q_relaxations=np.sum(np.asarray(carry.relaxations),
                                      axis=0)[:k],
-                stale_merges=np.sum(np.asarray(carry.stale), dtype=np.int32),
-                resends=np.sum(np.asarray(carry.resent), dtype=np.int32),
+                stale_merges=np.sum(np.asarray(carry.stale), dtype=np.int64),
+                resends=np.sum(np.asarray(carry.resent), dtype=np.int64),
                 n_dispatches=np.int32(
                     int(np.asarray(carry.rounds))
                     * dispatches_per_round(self.shards, self.cfg)),
                 overlap_rounds=np.int32(np.asarray(carry.overlap)),
-                bytes_moved=np.int32(np.asarray(carry.comm_bytes)))
+                bytes_moved=np.int64(np.asarray(carry.comm_bytes)))
         else:
             tc = time.perf_counter()
             if warm:
@@ -417,7 +446,8 @@ class SsspEngine:
         # over a dropped message is not.
         if self.certify:
             if self.backend == "sim":
-                improved = np.asarray(self._cert_fn(dist_pk))[:k]
+                improved = np.asarray(self._cert_fn(self.shards,
+                                                    dist_pk))[:k]
             else:
                 if self._cert_shmap is None:
                     self._cert_shmap = build_shmap_certificate(
@@ -692,11 +722,13 @@ def engine_for(sh: SsspShards, cfg: SsspConfig, backend: str = "sim",
     holds shards + cfg instead of a session)."""
     axes = tuple(axis_names) if axis_names else None
     key = (id(sh), cfg, backend, None if mesh is None else id(mesh), axes)
-    eng = _ENGINE_CACHE.get(key)
-    if eng is not None and eng.shards is sh and eng.mesh is mesh:
-        return eng
+    # the entry keeps the caller's shards object: a shmap engine holds a
+    # device-placed copy, so eng.shards is not the identity to compare
+    hit = _ENGINE_CACHE.get(key)
+    if hit is not None and hit[0] is sh and hit[1].mesh is mesh:
+        return hit[1]
     eng = SsspEngine(sh, cfg, backend, mesh, axes)
     if len(_ENGINE_CACHE) >= _ENGINE_CACHE_MAX:
         _ENGINE_CACHE.pop(next(iter(_ENGINE_CACHE)))
-    _ENGINE_CACHE[key] = eng
+    _ENGINE_CACHE[key] = (sh, eng)
     return eng

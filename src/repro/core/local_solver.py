@@ -18,8 +18,8 @@ before communicating) is iterated *frontier-masked relaxation*:
   ``pallas_call`` (no XLA re-entry per sweep, no scatter lowering); a thin
   ``lax.while_loop`` re-invokes the kernel on the residual frontier until
   empty. Requires the dst-tiled edge layout precomputed by
-  ``build_shards`` (``SsspShards.rx_*``); falls back to ``bellman`` with a
-  one-time warning when the layout is absent.
+  ``build_shards`` (``SsspShards.rx_*``); raises when the layout is
+  absent.
 
 All functions operate on ONE shard's local arrays (no leading P dim); the
 driver vmaps (sim backend) or shard_maps (distributed backend) over shards.
@@ -107,8 +107,8 @@ def local_fixpoint_delta(dist, active, loc_src, loc_dst, loc_w, pruned_loc,
 
 
 def local_fixpoint_pallas(dist, active, pruned_loc, relax_layout, *,
-                          vb: int, max_iters: int, sweeps: int = 8,
-                          interpret: bool = True) -> LocalResult:
+                          vb: int, max_iters: int, sweeps: int = 8
+                          ) -> LocalResult:
     """Fused Pallas fixpoint over the precomputed dst-tiled edge layout.
 
     ``relax_layout`` = (src_t, w_t, dstrel_t, eid_t), each
@@ -117,15 +117,14 @@ def local_fixpoint_pallas(dist, active, pruned_loc, relax_layout, *,
     """
     res = local_fixpoint_pallas_batch(dist[None], active[None], pruned_loc,
                                       relax_layout, vb=vb,
-                                      max_iters=max_iters, sweeps=sweeps,
-                                      interpret=interpret)
+                                      max_iters=max_iters, sweeps=sweeps)
     return LocalResult(dist=res.dist[0], changed=res.changed[0],
                        relaxations=res.relaxations[0])
 
 
 def local_fixpoint_pallas_batch(dist, active, pruned_loc, relax_layout, *,
-                                vb: int, max_iters: int, sweeps: int = 8,
-                                interpret: bool = True) -> LocalResult:
+                                vb: int, max_iters: int, sweeps: int = 8
+                                ) -> LocalResult:
     """Batched pallas fixpoint: dist/active are [K, block]; the dst-tiled
     layout AND the tiled Trishla mask are shared — gathered once, reused by
     every query in the batch (the amortization the batch engine exists for).
@@ -160,11 +159,11 @@ def local_fixpoint_pallas_batch(dist, active, pruned_loc, relax_layout, *,
         if ctile is None:
             new_d, resid, n = relax_fixpoint_batch_pallas(
                 d, front, src_t, w_t, dstrel_t, pruned_t, vb=vb, eb=eb,
-                n_sweeps=sweeps, interpret=interpret)
+                n_sweeps=sweeps)
         else:
             new_d, resid, n = relax_fixpoint_batch_ragged_pallas(
                 d, front, ctile, src_t, w_t, dstrel_t, pruned_t, vb=vb,
-                eb=eb, n_sweeps=sweeps, interpret=interpret)
+                eb=eb, n_sweeps=sweeps)
         return new_d, resid, nrel + n, it + jnp.int32(sweeps)
 
     out = jax.lax.while_loop(
@@ -183,8 +182,8 @@ def local_fixpoint_pallas_batch(dist, active, pruned_loc, relax_layout, *,
 
 @phases.register("local_solver", "bellman")
 def _batch_bellman(dist, active, loc_src, loc_dst, loc_w, pruned_loc, *,
-                   max_iters, delta, relax_layout, relax_vb, pallas_sweeps,
-                   pallas_interpret) -> LocalResult:
+                   max_iters, delta, relax_layout, relax_vb, pallas_sweeps
+                   ) -> LocalResult:
     return jax.vmap(partial(local_fixpoint_bellman, loc_src=loc_src,
                             loc_dst=loc_dst, loc_w=loc_w,
                             pruned_loc=pruned_loc,
@@ -193,8 +192,8 @@ def _batch_bellman(dist, active, loc_src, loc_dst, loc_w, pruned_loc, *,
 
 @phases.register("local_solver", "delta")
 def _batch_delta(dist, active, loc_src, loc_dst, loc_w, pruned_loc, *,
-                 max_iters, delta, relax_layout, relax_vb, pallas_sweeps,
-                 pallas_interpret) -> LocalResult:
+                 max_iters, delta, relax_layout, relax_vb, pallas_sweeps
+                 ) -> LocalResult:
     return jax.vmap(partial(local_fixpoint_delta, loc_src=loc_src,
                             loc_dst=loc_dst, loc_w=loc_w,
                             pruned_loc=pruned_loc, max_iters=max_iters,
@@ -203,48 +202,43 @@ def _batch_delta(dist, active, loc_src, loc_dst, loc_w, pruned_loc, *,
 
 @phases.register("local_solver", "pallas")
 def _batch_pallas(dist, active, loc_src, loc_dst, loc_w, pruned_loc, *,
-                  max_iters, delta, relax_layout, relax_vb, pallas_sweeps,
-                  pallas_interpret) -> LocalResult:
+                  max_iters, delta, relax_layout, relax_vb, pallas_sweeps
+                  ) -> LocalResult:
     return local_fixpoint_pallas_batch(dist, active, pruned_loc, relax_layout,
                                        vb=relax_vb, max_iters=max_iters,
-                                       sweeps=pallas_sweeps,
-                                       interpret=pallas_interpret)
+                                       sweeps=pallas_sweeps)
 
 
 def local_fixpoint_batch(dist, active, loc_src, loc_dst, loc_w, pruned_loc, *,
                          solver: str = "bellman", max_iters: int = 10_000,
                          delta: float = 4.0, relax_layout=None,
-                         relax_vb: int = 128, pallas_sweeps: int = 8,
-                         pallas_interpret: bool = True) -> LocalResult:
+                         relax_vb: int = 128, pallas_sweeps: int = 8
+                         ) -> LocalResult:
     """Multi-query local solve: dist/active carry a leading [K] query axis;
     the edge arrays and the pruned mask are per-shard (query-invariant).
     Returns LocalResult with dist [K, block], changed [K], relaxations [K].
     """
     if solver == "pallas" and relax_layout is None:
-        phases.warn_once(
-            "local_solver.pallas.no_layout",
-            "local_solver='pallas' falling back to 'bellman': the shards "
-            "carry no dst-tiled edge layout (build_shards was called with "
+        raise ValueError(
+            "local_solver='pallas' needs the dst-tiled edge layout, but the "
+            "shards carry none (build_shards was called with "
             "relax_layout=False)")
-        solver = "bellman"
     impl = phases.resolve("local_solver", solver)
     return impl(dist, active, loc_src, loc_dst, loc_w, pruned_loc,
                 max_iters=max_iters, delta=delta, relax_layout=relax_layout,
-                relax_vb=relax_vb, pallas_sweeps=pallas_sweeps,
-                pallas_interpret=pallas_interpret)
+                relax_vb=relax_vb, pallas_sweeps=pallas_sweeps)
 
 
 def local_fixpoint(dist, active, loc_src, loc_dst, loc_w, pruned_loc, *,
                    solver: str = "bellman", max_iters: int = 10_000,
                    delta: float = 4.0, relax_layout=None, relax_vb: int = 128,
-                   pallas_sweeps: int = 8,
-                   pallas_interpret: bool = True) -> LocalResult:
+                   pallas_sweeps: int = 8) -> LocalResult:
     """Single-query local solve: a K=1 batch (the batched entry point owns
-    the solver dispatch and the pallas-layout fallback rule)."""
+    the solver dispatch and the pallas-layout check)."""
     res = local_fixpoint_batch(
         dist[None], active[None], loc_src, loc_dst, loc_w, pruned_loc,
         solver=solver, max_iters=max_iters, delta=delta,
         relax_layout=relax_layout, relax_vb=relax_vb,
-        pallas_sweeps=pallas_sweeps, pallas_interpret=pallas_interpret)
+        pallas_sweeps=pallas_sweeps)
     return LocalResult(dist=res.dist[0], changed=res.changed[0],
                        relaxations=res.relaxations[0])

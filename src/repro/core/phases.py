@@ -49,8 +49,6 @@ stages); this module stays dependency-free so anything may import it.
 """
 from __future__ import annotations
 
-import warnings
-
 _REGISTRY: dict[str, dict[str, object]] = {}
 
 
@@ -84,18 +82,3 @@ def validate(phase: str, name: str) -> str:
     """``resolve`` for its side effect only; returns ``name`` unchanged."""
     resolve(phase, name)
     return name
-
-
-# -------------------------------------------------------------------------
-# one-time warnings (pallas backends silently degrading to XLA would hide
-# a perf cliff; warn once per process, not once per trace)
-# -------------------------------------------------------------------------
-
-_WARNED: set[str] = set()
-
-
-def warn_once(key: str, message: str) -> None:
-    if key in _WARNED:
-        return
-    _WARNED.add(key)
-    warnings.warn(message, UserWarning, stacklevel=3)

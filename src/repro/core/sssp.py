@@ -69,7 +69,6 @@ import numpy as np
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
 from repro.core import faults as faults_mod
 from repro.core import phases
 from repro.core import warmstart  # noqa: F401  (registers warm_init backends)
@@ -102,7 +101,6 @@ class SsspConfig:
     delta: float = 4.0
     local_iters: int = 10_000
     pallas_sweeps: int = 8          # relaxation sweeps fused per pallas_call
-    pallas_interpret: bool = True   # interpret mode (CPU); False on real TPU
     prune_online: bool = True       # Trishla in the idle branch
     prune_offline_passes: int = 0   # vectorized Trishla before the solve
     tri_chunk: int = 256
@@ -205,8 +203,7 @@ def _phase_local(shard: SsspShards, dist, active, pruned, cursor, cfg: SsspConfi
             pruned[:e_loc], solver=cfg.local_solver,
             max_iters=cfg.local_iters, delta=cfg.delta,
             relax_layout=shard.relax_layout, relax_vb=shard.rx_vb,
-            pallas_sweeps=cfg.pallas_sweeps,
-            pallas_interpret=cfg.pallas_interpret)
+            pallas_sweeps=cfg.pallas_sweeps)
         return res.dist, pruned, cursor, res.relaxations, jnp.int32(0)
 
     def prune(dist, pruned, cursor):
@@ -283,8 +280,7 @@ def _phase_send_pallas(shard: SsspShards, dist, pruned, last_sent, *,
                         mode="fill", fill_value=0)
     send_val, new_last, sends = send_pack_pallas(
         dist, last_sent, shard.slot_valid, src_t, w_t, segrel_t, pruned_t,
-        ctile, sb=shard.tx_sb, eb=shard.tx_eb,
-        interpret=cfg.pallas_interpret)
+        ctile, sb=shard.tx_sb, eb=shard.tx_eb)
     if dense:
         payload = _scatter_dense(shard, send_val, dist.shape[1])
     else:
@@ -337,7 +333,7 @@ def _phase_merge_pallas(shard: SsspShards, dist, incoming, *, dense: bool,
         ctile = None
     return merge_scatter_pallas(
         dist, incoming.reshape(nq, -1), mx_pos, mx_dstrel, mx_valid, ctile,
-        vb=shard.mx_vb, eb=shard.mx_eb, interpret=cfg.pallas_interpret)
+        vb=shard.mx_vb, eb=shard.mx_eb)
 
 
 # --------------------------------------------------------------------------
@@ -611,19 +607,16 @@ phases.register("round", "fused")("fused")
 
 def _round_mode(sh: SsspShards, cfg: SsspConfig) -> str:
     """Resolved round pipeline. ``round='fused'`` needs ALL THREE tiled
-    layouts (relax ``rx_*``, send ``tx_*``, merge ``mx_*``); when any is
-    missing the fused backend degrades to the staged pipeline with a
-    one-time warning, mirroring the per-phase pallas fallbacks."""
+    layouts (relax ``rx_*``, send ``tx_*``, merge ``mx_*``) and raises when
+    any is missing, like the per-phase pallas backends."""
     if cfg.round != "fused":
         return "staged"
     if sh.has_relax_layout and sh.has_send_layout and sh.has_merge_layout:
         return "fused"
-    phases.warn_once(
-        "round.fused.no_layout",
-        "round='fused' falling back to the staged pipeline: the shards are "
-        "missing the dst-/slot-/msg-tiled layouts (build_shards was called "
-        "with relax_layout=False or comm_layout=False)")
-    return "staged"
+    raise ValueError(
+        "round='fused' needs the dst-/slot-/msg-tiled layouts, but the "
+        "shards are missing some (build_shards was called with "
+        "relax_layout=False or comm_layout=False)")
 
 
 def dispatches_per_round(sh: SsspShards, cfg: SsspConfig) -> int:
@@ -808,37 +801,30 @@ def build_pipeline(sh: SsspShards, cfg: SsspConfig) -> RoundPipeline:
     """Resolve every phase backend for these shards.
 
     Pallas send/merge backends need the ``tx_*``/``mx_*`` layouts from
-    ``build_shards``; when absent (``comm_layout=False``) they degrade to
-    the XLA backends with a one-time warning, mirroring the pallas local
-    solver's ``relax_layout`` rule. An active ``cfg.faults`` plan wraps
-    the resolved exchange stage with the fault-injecting decorator
-    (:func:`repro.core.faults.wrap_exchange`) — the transfer itself is
-    untouched; delivery goes through the injector."""
+    ``build_shards``; when absent (``comm_layout=False``) this raises,
+    like the pallas local solver's ``relax_layout`` rule. An active
+    ``cfg.faults`` plan wraps the resolved exchange stage with the
+    fault-injecting decorator (:func:`repro.core.faults.wrap_exchange`) —
+    the transfer itself is untouched; delivery goes through the injector."""
     ex = phases.resolve("exchange", cfg.exchange)
     if cfg.fault_plan is not None:
         ex = faults_mod.wrap_exchange(ex, cfg.fault_plan)
-    send_backend = cfg.send_backend
-    if send_backend == "pallas" and not sh.has_send_layout:
-        phases.warn_once(
-            "send.pallas.no_layout",
-            "send_backend='pallas' falling back to 'xla': the shards carry "
-            "no slot-tiled cut-edge layout (build_shards was called with "
+    if cfg.send_backend == "pallas" and not sh.has_send_layout:
+        raise ValueError(
+            "send_backend='pallas' needs the slot-tiled cut-edge layout, but "
+            "the shards carry none (build_shards was called with "
             "comm_layout=False)")
-        send_backend = "xla"
-    merge_backend = cfg.merge_backend
-    if merge_backend == "pallas" and not sh.has_merge_layout:
-        phases.warn_once(
-            "merge.pallas.no_layout",
-            "merge_backend='pallas' falling back to 'xla': the shards carry "
-            "no msg-tiled receive layout (build_shards was called with "
+    if cfg.merge_backend == "pallas" and not sh.has_merge_layout:
+        raise ValueError(
+            "merge_backend='pallas' needs the msg-tiled receive layout, but "
+            "the shards carry none (build_shards was called with "
             "comm_layout=False)")
-        merge_backend = "xla"
     return RoundPipeline(
         local=partial(_phase_local, cfg=cfg),
-        send=partial(phases.resolve("send", send_backend),
+        send=partial(phases.resolve("send", cfg.send_backend),
                      dense=ex.dense, cfg=cfg),
         exchange=ex,
-        merge=partial(phases.resolve("merge", merge_backend),
+        merge=partial(phases.resolve("merge", cfg.merge_backend),
                       dense=ex.dense, cfg=cfg),
         toka=phases.resolve("toka", cfg.toka))
 
@@ -859,8 +845,7 @@ def _phase_fused(shard: SsspShards, dist, front_in, live, incoming, last_sent,
         dist, front_in, live, inc, last_sent, shard.slot_valid,
         shard.relax_layout, shard.send_layout, shard.merge_layout,
         pruned[:e_loc], pruned[e_loc:], vb=shard.rx_vb, sb=shard.tx_sb,
-        n_sweeps=cfg.pallas_sweeps, dense=dense,
-        interpret=cfg.pallas_interpret)
+        n_sweeps=cfg.pallas_sweeps, dense=dense)
     if dense:
         payload = _scatter_dense(shard, send_val, dist.shape[1])
     else:
@@ -880,7 +865,7 @@ def _phase_fused_rescue(shard: SsspShards, dist, resid, last_sent, pruned, *,
         dist, resid, last_sent, shard.slot_valid, shard.relax_layout,
         shard.send_layout, pruned[:e_loc], pruned[e_loc:], vb=shard.rx_vb,
         sb=shard.tx_sb, n_sweeps=cfg.pallas_sweeps,
-        max_iters=cfg.local_iters, interpret=cfg.pallas_interpret)
+        max_iters=cfg.local_iters)
     if dense:
         payload = _scatter_dense(shard, send_val, dist.shape[1])
     else:
@@ -1494,7 +1479,7 @@ def build_shmap_certificate(sh_spec: SsspShards, mesh, axis_names,
 
     pspec = P(axes)
     in_specs = (jax.tree_util.tree_map(lambda _: pspec, sh_spec), pspec)
-    shm = compat.shard_map(body, mesh=mesh, in_specs=in_specs,
+    shm = jax.shard_map(body, mesh=mesh, in_specs=in_specs,
                            out_specs=P(), check_vma=False)
 
     def run(stacked, dist):
@@ -1595,7 +1580,7 @@ def build_shmap_solver_traced(sh_spec: SsspShards, cfg: SsspConfig, mesh,
     in_specs = jax.tree_util.tree_map(lambda _: pspec, sh_spec)
     in_specs = (in_specs, rspec, rspec) + ((pspec,) if warm else ())
     out_specs = (pspec, SsspStats(*([rspec] * len(SsspStats._fields))))
-    shm = compat.shard_map(body, mesh=mesh, in_specs=in_specs,
+    shm = jax.shard_map(body, mesh=mesh, in_specs=in_specs,
                            out_specs=out_specs, check_vma=False)
 
     def run(stacked, sources, q_valid, *warm_args):

@@ -18,6 +18,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.runtime import pallas_interpret
+
 
 def _embag_kernel(idx_ref, table_ref, out_ref, *, bb: int, L: int, mean: bool):
     V, D = table_ref.shape
@@ -41,7 +43,7 @@ def _embag_kernel(idx_ref, table_ref, out_ref, *, bb: int, L: int, mean: bool):
 
 
 def embedding_bag_p(table, indices, *, mode: str = "sum", bb: int = 8,
-                    interpret: bool = True):
+                    interpret: bool | None = None):
     """table: [V, D]; indices: [B, L] (B % bb == 0). Returns [B, D]."""
     B, L = indices.shape
     V, D = table.shape
@@ -56,5 +58,5 @@ def embedding_bag_p(table, indices, *, mode: str = "sum", bb: int = 8,
         ],
         out_specs=pl.BlockSpec((bb, D), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((B, D), table.dtype),
-        interpret=interpret,
+        interpret=pallas_interpret(interpret),
     )(indices, table)

@@ -11,7 +11,7 @@ from repro.kernels.embedding_bag.embedding_bag import embedding_bag_p
 
 @partial(jax.jit, static_argnames=("mode", "bb", "interpret"))
 def embedding_bag(table, indices, *, mode: str = "sum", bb: int = 8,
-                  interpret: bool = True):
+                  interpret: bool | None = None):
     """Pallas path. Pads the bag axis to a multiple of ``bb``."""
     B, L = indices.shape
     pad = (-B) % bb

@@ -18,6 +18,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 import jax.experimental.pallas.tpu as pltpu
 
+from repro.runtime import pallas_interpret
+
 
 def _flash_kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
                   scale: float, causal: bool, q_offset: int, kv_len: int,
@@ -67,7 +69,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
 
 def flash_attention_p(q, k, v, *, scale: float, causal: bool, q_offset: int,
                       kv_len: int, block_q: int, block_k: int,
-                      interpret: bool = True):
+                      interpret: bool | None = None):
     """q: [B, Hq, Sq_pad, D]; k/v: [B, Hkv, Skv_pad, D] (pre-padded)."""
     B, Hq, Sq, D = q.shape
     _, Hkv, Skv, _ = k.shape
@@ -96,5 +98,5 @@ def flash_attention_p(q, k, v, *, scale: float, causal: bool, q_offset: int,
             pltpu.VMEM((block_q,), jnp.float32),     # m (running max)
             pltpu.VMEM((block_q,), jnp.float32),     # l (running denom)
         ],
-        interpret=interpret,
+        interpret=pallas_interpret(interpret),
     )(q, k, v)

@@ -23,7 +23,7 @@ def _pad_seq(x, block, axis):
                                    "block_k", "interpret"))
 def flash_attention(q, k, v, *, causal: bool = True, scale: float | None = None,
                     q_offset: int = 0, block_q: int = 128, block_k: int = 128,
-                    interpret: bool = True):
+                    interpret: bool | None = None):
     """q: [B, Hq, Sq, D]; k/v: [B, Hkv, Skv, D]. Returns [B, Hq, Sq, D].
 
     ``q_offset`` positions queries for causal decode (q_offset = Skv - Sq)."""

@@ -40,6 +40,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.tile_reduce import tile_min_batch
+from repro.runtime import pallas_interpret
 
 INF = float("inf")
 
@@ -88,7 +89,7 @@ def _merge_scatter_kernel(dist_ref, in_ref, pos_ref, dstrel_ref, valid_ref,
 
 
 def merge_scatter_tiled(dist_pad, incoming_flat, pos_t, dstrel_t, valid_t, *,
-                        vb: int, eb: int, interpret: bool = True):
+                        vb: int, eb: int, interpret: bool | None = None):
     """dist_pad: [K, block_pad] f32 (block_pad = n_vtiles * vb);
     incoming_flat: [K, M] f32 flattened messages; pos_t/dstrel_t/valid_t:
     [n_vtiles, n_chunks, EB] msg-tiled routing layout (query-invariant).
@@ -120,7 +121,7 @@ def merge_scatter_tiled(dist_pad, incoming_flat, pos_t, dstrel_t, valid_t, *,
             jax.ShapeDtypeStruct((nq,), jnp.int32),
         ],
         scratch_shapes=[pltpu.SMEM((nq,), jnp.int32)],
-        interpret=interpret,
+        interpret=pallas_interpret(interpret),
     )(dist_pad, incoming_flat, pos_t, dstrel_t, valid_t)
 
 
@@ -164,7 +165,7 @@ def _merge_scatter_ragged_kernel(ctile_ref, dist_ref, in_ref, pos_ref,
 
 def merge_scatter_ragged(dist_pad, incoming_flat, ctile, pos_r, dstrel_r,
                          valid_r, *, vb: int, eb: int,
-                         interpret: bool = True):
+                         interpret: bool | None = None):
     """Ragged counterpart of ``merge_scatter_tiled``: pos_r/dstrel_r/valid_r
     are flat [total_chunks, EB] rows, ``ctile`` the [total_chunks] chunk→
     tile map (sentinel ``n_vtiles`` for inert padding chunks). Same
@@ -200,5 +201,5 @@ def merge_scatter_ragged(dist_pad, incoming_flat, ctile, pos_r, dstrel_r,
             jax.ShapeDtypeStruct((nq, bp), jnp.float32),
             jax.ShapeDtypeStruct((nq,), jnp.int32),
         ],
-        interpret=interpret,
+        interpret=pallas_interpret(interpret),
     )(ctile, dist_pad, incoming_flat, pos_r, dstrel_r, valid_r)

@@ -105,7 +105,7 @@ def build_msg_tiled_layout(recv_idx, block: int, *, vb: int = 128,
 @partial(jax.jit, static_argnames=("vb", "eb", "interpret"))
 def merge_scatter_pallas(dist, incoming_flat, pos_t, dstrel_t, valid_t,
                          ctile=None, *, vb: int = 128, eb: int = 512,
-                         interpret: bool = True):
+                         interpret: bool | None = None):
     """Solver-facing wrapper: pads to kernel tile shapes, slices back.
 
     dist: [K, block]; incoming_flat: [K, M] flattened bucketed messages.

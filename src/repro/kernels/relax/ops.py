@@ -127,7 +127,7 @@ def build_dst_ragged_layout(src, dst, w, n_vertices: int, *, vb: int = 128,
 
 @partial(jax.jit, static_argnames=("vb", "eb", "interpret"))
 def relax_pallas(dist_pad, src_t, w_t, dstrel_t, *, vb: int = 128,
-                 eb: int = 512, interpret: bool = True):
+                 eb: int = 512, interpret: bool | None = None):
     return relax_dst_tiled(dist_pad, src_t, w_t, dstrel_t, vb=vb, eb=eb,
                            interpret=interpret)
 
@@ -135,7 +135,7 @@ def relax_pallas(dist_pad, src_t, w_t, dstrel_t, *, vb: int = 128,
 @partial(jax.jit, static_argnames=("vb", "eb", "interpret"))
 def relax_masked_pallas(dist_pad, front_pad, src_t, w_t, dstrel_t, pruned_t,
                         *, vb: int = 128, eb: int = 512,
-                        interpret: bool = True):
+                        interpret: bool | None = None):
     """One frontier-masked sweep. Returns (new_dist, n_relax scalar)."""
     new, nrel = relax_dst_tiled_masked(dist_pad, front_pad, src_t, w_t,
                                        dstrel_t, pruned_t, vb=vb, eb=eb,
@@ -146,7 +146,7 @@ def relax_masked_pallas(dist_pad, front_pad, src_t, w_t, dstrel_t, pruned_t,
 @partial(jax.jit, static_argnames=("vb", "eb", "n_sweeps", "interpret"))
 def relax_fixpoint_pallas(dist_pad, front_pad, src_t, w_t, dstrel_t, pruned_t,
                           *, vb: int = 128, eb: int = 512, n_sweeps: int = 8,
-                          interpret: bool = True):
+                          interpret: bool | None = None):
     """Fused multi-sweep solve. Returns (new_dist, residual_frontier, n_relax)."""
     new, resid, nrel = relax_dst_tiled_fixpoint(
         dist_pad, front_pad, src_t, w_t, dstrel_t, pruned_t, vb=vb, eb=eb,
@@ -157,7 +157,7 @@ def relax_fixpoint_pallas(dist_pad, front_pad, src_t, w_t, dstrel_t, pruned_t,
 @partial(jax.jit, static_argnames=("vb", "eb", "n_sweeps", "interpret"))
 def relax_fixpoint_batch_pallas(dist_pad, front_pad, src_t, w_t, dstrel_t,
                                 pruned_t, *, vb: int = 128, eb: int = 512,
-                                n_sweeps: int = 8, interpret: bool = True):
+                                n_sweeps: int = 8, interpret: bool | None = None):
     """Batched fused solve over a leading query axis K (shared edge layout).
 
     dist_pad/front_pad: [K, block_pad]. Returns (new_dist [K, block_pad],
@@ -171,7 +171,7 @@ def relax_fixpoint_batch_pallas(dist_pad, front_pad, src_t, w_t, dstrel_t,
 def relax_fixpoint_batch_ragged_pallas(dist_pad, front_pad, ctile, src_r, w_r,
                                        dstrel_r, pruned_r, *, vb: int = 128,
                                        eb: int = 512, n_sweeps: int = 8,
-                                       interpret: bool = True):
+                                       interpret: bool | None = None):
     """Ragged-grid batched fused solve (CSR-chunked layout + chunk→tile map).
 
     Same contract as ``relax_fixpoint_batch_pallas`` with the flat

@@ -6,8 +6,11 @@ destination* (host-side, one-time — the layout is as static as the CSR
 itself) and each grid step produces one VB-wide vertex tile with a one-hot
 masked min-reduce, which is pure VPU work over an [EB, VB] tile held in
 VMEM. The source-distance gather is a 1-D dynamic gather from the
-VMEM-resident distance vector (Mosaic ``DynamicGatherOp``; validated here
-in interpret mode since the container is CPU-only).
+VMEM-resident distance vector. The v5e compiler refuses that gather
+("Only 2D gather is supported") and the ``(1, EB)`` edge-chunk blocks
+(last two block dimensions must be divisible by 8 and 128), so these
+kernels run only in interpret mode on the CPU today
+(``tests/test_tpu_compile.py`` records the refusal).
 
 Four entry points, in increasing integration with the solver:
 
@@ -63,6 +66,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.tile_reduce import tile_min
+from repro.runtime import pallas_interpret
 
 INF = float("inf")
 
@@ -87,7 +91,7 @@ def _relax_kernel(dist_ref, src_ref, w_ref, dstrel_ref, out_ref, *, vb: int):
 
 
 def relax_dst_tiled(dist_pad, src_t, w_t, dstrel_t, *, vb: int, eb: int,
-                    interpret: bool = True):
+                    interpret: bool | None = None):
     """dist_pad: [block_pad] f32 (block_pad % vb == 0).
     src_t/w_t/dstrel_t: [n_vtiles, n_chunks, EB] dst-tiled edge layout.
     Returns new distances [block_pad]."""
@@ -107,7 +111,7 @@ def relax_dst_tiled(dist_pad, src_t, w_t, dstrel_t, *, vb: int, eb: int,
         ],
         out_specs=pl.BlockSpec((vb,), lambda i, j: (i,)),
         out_shape=jax.ShapeDtypeStruct((n_vtiles * vb,), dist_pad.dtype),
-        interpret=interpret,
+        interpret=pallas_interpret(interpret),
     )(dist_pad, src_t, w_t, dstrel_t)
 
 
@@ -154,7 +158,7 @@ def _relax_masked_kernel(dist_ref, front_ref, src_ref, w_ref, dstrel_ref,
 
 def relax_dst_tiled_masked(dist_pad, front_pad, src_t, w_t, dstrel_t,
                            pruned_t, *, vb: int, eb: int,
-                           interpret: bool = True):
+                           interpret: bool | None = None):
     """One frontier-masked, Trishla-pruned sweep with relaxation counting.
 
     front_pad: [block_pad] f32 0/1; pruned_t: [n_vtiles, n_chunks, EB] int32
@@ -184,7 +188,7 @@ def relax_dst_tiled_masked(dist_pad, front_pad, src_t, w_t, dstrel_t,
             jax.ShapeDtypeStruct((1,), jnp.int32),
         ],
         scratch_shapes=[pltpu.SMEM((1,), jnp.int32)],
-        interpret=interpret,
+        interpret=pallas_interpret(interpret),
     )(dist_pad, front_pad, src_t, w_t, dstrel_t, pruned_t)
 
 
@@ -238,7 +242,7 @@ def _relax_fixpoint_kernel(dist_ref, front_ref, src_ref, w_ref, dstrel_ref,
 
 def relax_dst_tiled_fixpoint(dist_pad, front_pad, src_t, w_t, dstrel_t,
                              pruned_t, *, vb: int, eb: int, n_sweeps: int,
-                             interpret: bool = True):
+                             interpret: bool | None = None):
     """Fused multi-sweep local solve: up to ``n_sweeps`` frontier-chased
     relaxation sweeps inside one ``pallas_call``.
 
@@ -275,7 +279,7 @@ def relax_dst_tiled_fixpoint(dist_pad, front_pad, src_t, w_t, dstrel_t,
             pltpu.VMEM((bp,), jnp.float32),              # current frontier
             pltpu.SMEM((2,), jnp.int32),                 # active flag, count
         ],
-        interpret=interpret,
+        interpret=pallas_interpret(interpret),
     )(dist_pad, front_pad, src_t, w_t, dstrel_t, pruned_t)
 
 
@@ -336,7 +340,7 @@ def _relax_fixpoint_batch_kernel(dist_ref, front_ref, src_ref, w_ref,
 
 def relax_dst_tiled_fixpoint_batch(dist_pad, front_pad, src_t, w_t, dstrel_t,
                                    pruned_t, *, vb: int, eb: int,
-                                   n_sweeps: int, interpret: bool = True):
+                                   n_sweeps: int, interpret: bool | None = None):
     """Batched multi-query fixpoint: ``dist_pad``/``front_pad`` are
     [K, block_pad]; the dst-tiled edge layout (and the Trishla pruned mask)
     is SHARED by all K queries — built/gathered once, streamed once per
@@ -383,7 +387,7 @@ def relax_dst_tiled_fixpoint_batch(dist_pad, front_pad, src_t, w_t, dstrel_t,
             pltpu.SMEM((nq,), jnp.int32),                # per-query active
             pltpu.SMEM((nq,), jnp.int32),                # per-query count
         ],
-        interpret=interpret,
+        interpret=pallas_interpret(interpret),
     )(dist_pad, front_pad, src_t, w_t, dstrel_t, pruned_t)
 
 
@@ -455,7 +459,7 @@ def _relax_ragged_fixpoint_batch_kernel(ctile_ref, dist_ref, front_ref,
 
 def relax_dst_ragged_fixpoint_batch(dist_pad, front_pad, ctile, src_r, w_r,
                                     dstrel_r, pruned_r, *, vb: int, eb: int,
-                                    n_sweeps: int, interpret: bool = True):
+                                    n_sweeps: int, interpret: bool | None = None):
     """Ragged counterpart of ``relax_dst_tiled_fixpoint_batch``.
 
     ``src_r``/``w_r``/``dstrel_r``/``pruned_r`` are [total_chunks, EB] flat
@@ -500,5 +504,5 @@ def relax_dst_ragged_fixpoint_batch(dist_pad, front_pad, ctile, src_r, w_r,
             jax.ShapeDtypeStruct((nq, bp), jnp.float32),
             jax.ShapeDtypeStruct((nq,), jnp.int32),
         ],
-        interpret=interpret,
+        interpret=pallas_interpret(interpret),
     )(ctile, dist_pad, front_pad, src_r, w_r, dstrel_r, pruned_r)

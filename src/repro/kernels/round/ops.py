@@ -52,7 +52,7 @@ def fused_round_pallas(dist, front_in, live, incoming, last_sent, slot_valid,
                        relax_layout, send_layout, merge_layout, pruned_loc,
                        pruned_cut, *, vb: int = 128, sb: int = 128,
                        n_sweeps: int = 8, dense: bool = False,
-                       interpret: bool = True):
+                       interpret: bool | None = None):
     """One fused merge + local-fixpoint + send-pack round on one shard.
 
     dist/front_in: [K, block]; live: [K] bool; incoming: [K, M] flattened
@@ -112,7 +112,7 @@ def fused_round_pallas(dist, front_in, live, incoming, last_sent, slot_valid,
 def fused_round_rescue(dist, resid, last_sent, slot_valid, relax_layout,
                        send_layout, pruned_loc, pruned_cut, *, vb: int = 128,
                        sb: int = 128, n_sweeps: int = 8,
-                       max_iters: int = 10_000, interpret: bool = True):
+                       max_iters: int = 10_000, interpret: bool | None = None):
     """Finish a round whose in-kernel sweeps left a residual frontier.
 
     ``dist``/``resid`` are the megakernel's merged-and-partially-relaxed

@@ -65,6 +65,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.tile_reduce import tile_min_batch
+from repro.runtime import pallas_interpret
 
 INF = float("inf")
 
@@ -219,7 +220,7 @@ def _stage_map(lo: int, hi: int, nt: int, nc: int):
 def fused_round_tiled(dist_pad, front_pad, live, incoming, last_pad,
                       valid_pad, mx_layout, rx_layout, tx_layout, *, vb: int,
                       sb: int, n_sweeps: int, dense: bool,
-                      interpret: bool = True):
+                      interpret: bool | None = None):
     """One fused round. dist_pad/front_pad: [K, block_pad]; live: [K] f32
     0/1; incoming: [K, M] flat messages (bucket) or [K, block_pad] remote
     minima (dense); last_pad/valid_pad: [K, S_pad] / [S_pad].
@@ -310,7 +311,7 @@ def fused_round_tiled(dist_pad, front_pad, live, incoming, last_pad,
             pltpu.SMEM((nq,), jnp.int32),         # relaxation counters
             pltpu.SMEM((nq,), jnp.int32),         # send counters
         ],
-        interpret=interpret,
+        interpret=pallas_interpret(interpret),
     )(*operands)
 
 
@@ -457,7 +458,7 @@ def _stage_map_ragged(lo: int, hi: int, nc: int):
 def fused_round_ragged(dist_pad, front_pad, live, incoming, last_pad,
                        valid_pad, mx_layout, rx_layout, tx_layout, *,
                        vb: int, sb: int, n_sweeps: int, dense: bool,
-                       interpret: bool = True):
+                       interpret: bool | None = None):
     """One fused round over ragged CSR-chunked layouts.
 
     Same contract as ``fused_round_tiled`` except each layout tuple gains
@@ -544,5 +545,5 @@ def fused_round_ragged(dist_pad, front_pad, live, incoming, last_pad,
             jax.ShapeDtypeStruct((nq,), jnp.int32),
             jax.ShapeDtypeStruct((nq,), jnp.int32),
         ],
-        interpret=interpret,
+        interpret=pallas_interpret(interpret),
     )(*scalars, *operands)

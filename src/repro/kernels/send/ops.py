@@ -55,7 +55,7 @@ def build_slot_tiled_layout(cut_src, cut_seg, cut_w, n_slots: int, *,
 @partial(jax.jit, static_argnames=("sb", "eb", "interpret"))
 def send_pack_pallas(dist, last_sent, slot_valid, src_t, w_t, segrel_t,
                      pruned_t, ctile=None, *, sb: int = 128, eb: int = 512,
-                     interpret: bool = True):
+                     interpret: bool | None = None):
     """Solver-facing wrapper: pads to kernel tile shapes, slices back.
 
     dist: [K, block]; last_sent: [K, S]; slot_valid: [S] bool;

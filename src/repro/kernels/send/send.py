@@ -42,6 +42,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.tile_reduce import tile_min_batch
+from repro.runtime import pallas_interpret
 
 INF = float("inf")
 
@@ -101,7 +102,7 @@ def _send_pack_kernel(dist_ref, last_ref, valid_ref, src_ref, w_ref,
 
 
 def send_pack_tiled(dist_pad, last_pad, valid_pad, src_t, w_t, segrel_t,
-                    pruned_t, *, sb: int, eb: int, interpret: bool = True):
+                    pruned_t, *, sb: int, eb: int, interpret: bool | None = None):
     """dist_pad: [K, block_pad] f32; last_pad/valid_pad: [K, S_pad] /
     [S_pad] with S_pad = n_stiles * sb; src_t/w_t/segrel_t/pruned_t:
     [n_stiles, n_chunks, EB] slot-tiled cut-edge layout (shared by all K
@@ -139,7 +140,7 @@ def send_pack_tiled(dist_pad, last_pad, valid_pad, src_t, w_t, segrel_t,
             jax.ShapeDtypeStruct((nq,), jnp.int32),
         ],
         scratch_shapes=[pltpu.SMEM((nq,), jnp.int32)],
-        interpret=interpret,
+        interpret=pallas_interpret(interpret),
     )(dist_pad, last_pad, valid_pad, src_t, w_t, segrel_t, pruned_t)
 
 
@@ -186,7 +187,7 @@ def _send_pack_ragged_kernel(ctile_ref, dist_ref, last_ref, valid_ref,
 
 def send_pack_ragged(dist_pad, last_pad, valid_pad, ctile, src_r, w_r,
                      segrel_r, pruned_r, *, sb: int, eb: int,
-                     interpret: bool = True):
+                     interpret: bool | None = None):
     """Ragged counterpart of ``send_pack_tiled``: the slot-tiled layout is
     flat [total_chunks, EB] rows plus the [total_chunks] chunk→tile map
     (sentinel ``n_stiles`` marks inert padding chunks, clamped in-kernel).
@@ -229,5 +230,5 @@ def send_pack_ragged(dist_pad, last_pad, valid_pad, ctile, src_r, w_r,
             jax.ShapeDtypeStruct((nq, sp), jnp.float32),
             jax.ShapeDtypeStruct((nq,), jnp.int32),
         ],
-        interpret=interpret,
+        interpret=pallas_interpret(interpret),
     )(ctile, dist_pad, last_pad, valid_pad, src_r, w_r, segrel_r, pruned_r)

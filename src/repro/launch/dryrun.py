@@ -22,7 +22,6 @@ import traceback
 
 import jax
 
-from repro import compat
 from repro.configs.registry import ARCHS, build_cell, list_cells
 
 ARCH_FAMILY = {a: fam for a, (fam, _) in ARCHS.items()}
@@ -36,7 +35,7 @@ ARTIFACT_DIR = os.path.join(os.path.dirname(__file__), "..", "..", "..",
 
 def _compile_and_measure(cell, mesh, loop_scale: int = 1) -> dict:
     t0 = time.time()
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         jitted = jax.jit(cell.step_fn, in_shardings=cell.in_shardings)
         lowered = jitted.lower(*cell.args_struct)
         t_lower = time.time() - t0
@@ -63,7 +62,7 @@ def _lower_cost_only(cell, mesh) -> dict:
     pre-optimization cost analysis (GLOBAL totals; divided by mesh.size
     for per-device roofline terms)."""
     t0 = time.time()
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         jitted = jax.jit(cell.step_fn, in_shardings=cell.in_shardings)
         lowered = jitted.lower(*cell.args_struct)
     cost = lowered.cost_analysis() or {}

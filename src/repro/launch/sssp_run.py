@@ -12,8 +12,9 @@ and a later run of the same bucket shape would reuse the compiled program):
     ... repro.launch.sssp_run --num-sources 16 --batch   # sampled batch
 
 Backends: ``sim`` (single device, any partition count) and ``shmap``
-(shard_map over real devices — on a TPU pod this is the deployment path;
-here it requires XLA_FLAGS device-count spoofing, see tests/test_multidevice).
+(shard_map over the first ``--parts`` devices, one shard per device; on
+a CPU host the devices come from
+``XLA_FLAGS=--xla_force_host_platform_device_count=N``).
 """
 from __future__ import annotations
 
@@ -25,6 +26,7 @@ import numpy as np
 from repro.core import FaultPlan, SsspConfig, SsspEngine, build_shards
 from repro.graph import (dijkstra_reference, rmat_graph, road_grid_graph,
                          random_graph)
+from repro.runtime import enable_compile_cache
 
 
 def main():
@@ -102,6 +104,7 @@ def main():
                         "drops and no resend, solves degrade)")
     p.add_argument("--validate", action="store_true")
     args = p.parse_args()
+    enable_compile_cache()
     if args.warm_start == "landmark" and args.landmarks < 1:
         p.error("--warm-start landmark requires --landmarks N (N >= 1)")
     if args.async_lag < 1:
@@ -155,8 +158,14 @@ def main():
     else:
         import jax
         from repro import compat
-        n_dev = len(jax.devices())
-        mesh = compat.make_mesh((n_dev,), ("data",))
+        devices = jax.devices()
+        if len(devices) < args.parts:
+            raise SystemExit(
+                f"--backend shmap places one shard per device: --parts "
+                f"{args.parts} needs {args.parts} devices, but only "
+                f"{len(devices)} are visible")
+        mesh = compat.make_mesh((args.parts,), ("data",),
+                                devices=devices[:args.parts])
         engine = SsspEngine.build(sh, cfg, backend="shmap", mesh=mesh,
                                   axis_names=("data",),
                                   result_cache=args.result_cache)

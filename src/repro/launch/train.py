@@ -22,7 +22,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro import compat
 from repro.checkpoint import CheckpointManager
 from repro.configs.registry import _load
 from repro.data import TokenStream, RecsysBatcher
@@ -125,7 +124,7 @@ def main(argv=None):
 
     it = iter(data)
     losses = []
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         t0 = time.time()
         for s in range(start, args.steps):
             batch = next(it)

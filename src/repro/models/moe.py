@@ -30,7 +30,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
 
 
 def _routing_group(topi_g, E: int, k: int, Cg: int):
@@ -134,7 +133,7 @@ def _expert_block_shmap(xg, slot_token, topi_g, pos, keep, topv_g,
         w=P_(ax.model, None, None),
         out=P_(ax.data, None, None),
     )
-    return compat.shard_map(
+    return jax.shard_map(
         body,
         in_specs=(specs["xg"], specs["slot"], specs["tok"], specs["tok"],
                   specs["tok"], specs["tok"], specs["w"], specs["w"],

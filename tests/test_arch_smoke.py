@@ -5,7 +5,6 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from repro import compat
 from repro.configs.registry import ARCHS, _load
 from repro.models.params import materialize
 from repro.optim import AdamWConfig
@@ -40,7 +39,7 @@ def _gnn_batch(arch, cfg):
 @pytest.mark.parametrize("arch", list(ARCHS))
 def test_arch_smoke(arch, mesh11, ax11):
     family, cfg = _load(arch, smoke=True)
-    with compat.set_mesh(mesh11):
+    with jax.set_mesh(mesh11):
         if family == "lm":
             from repro.models import transformer as tf
             defs = tf.param_defs(cfg, ax11)
@@ -99,7 +98,7 @@ def test_lm_decode_matches_forward(mesh11, ax11):
     params = materialize(defs, jax.random.key(1), cfg.dtype)
     B, S = 2, 24
     toks = jnp.asarray(rng.integers(0, cfg.vocab_size, (B, S)), jnp.int32)
-    with compat.set_mesh(mesh11):
+    with jax.set_mesh(mesh11):
         full_logits, _, _ = jax.jit(
             lambda p, t: tf.forward(p, t, cfg, ax11))(params, toks)
         # prefill first S-4 tokens, then decode the remaining 4 one by one
@@ -131,7 +130,7 @@ def test_mace_rotation_invariance(mesh11, ax11):
     base = dict(edge_src=jnp.asarray(rng.integers(0, N, E), jnp.int32),
                 edge_dst=jnp.asarray(rng.integers(0, N, E), jnp.int32),
                 node_feat=jnp.asarray(rng.integers(0, 10, (N, 1)), jnp.float32))
-    with compat.set_mesh(mesh11):
+    with jax.set_mesh(mesh11):
         h0 = gnn.mace_forward(params, dict(base, coords=jnp.asarray(coords)),
                               cfg, ax11)
         h1 = gnn.mace_forward(params, dict(base, coords=jnp.asarray(coords @ R.T)),

@@ -42,6 +42,13 @@ def test_warm_start_requires_landmarks(monkeypatch, capsys):
     assert "--landmarks" in capsys.readouterr().err
 
 
+def test_shmap_needs_a_device_per_part(monkeypatch, capsys):
+    # the CPU test process has one device; --parts 4 asks for four
+    monkeypatch.setattr(sys, "argv", ["sssp_run", *TINY, "--backend", "shmap"])
+    with pytest.raises(SystemExit, match="needs 4 devices, but only 1"):
+        sssp_run.main()
+
+
 def test_out_of_range_source_rejected(monkeypatch, capsys):
     monkeypatch.setattr(sys, "argv",
                         ["sssp_run", *TINY, "--sources", "999999"])

@@ -261,6 +261,20 @@ _SHMAP_ENGINE_PROG = textwrap.dedent("""
         raise SystemExit("out-of-range source accepted on shmap")
     except ValueError:
         pass
+
+    # the stack is placed once, one shard per device, at engine build
+    import jax
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    placed = NamedSharding(mesh, P(("d",)))
+    for leaf in jax.tree_util.tree_leaves(eng.shards):
+        assert leaf.sharding.is_equivalent_to(placed, leaf.ndim), leaf.sharding
+    # one shard per device: any other count is refused, never truncated
+    try:
+        SsspEngine.build(build_shards(g, 8), SsspConfig(), backend="shmap",
+                         mesh=mesh, axis_names=("d",))
+        raise SystemExit("8 shards accepted on a 4-device mesh")
+    except ValueError as e:
+        assert "one shard per device" in str(e), e
     print("SHMAP ENGINE OK")
 """)
 

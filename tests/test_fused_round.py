@@ -25,7 +25,6 @@ import os
 import subprocess
 import sys
 import textwrap
-import warnings
 
 import jax
 import jax.numpy as jnp
@@ -33,7 +32,7 @@ import numpy as np
 import pytest
 
 from _hyp import given, settings, strategies as st
-from repro.core import SsspConfig, build_shards, phases, solve_sim_batch
+from repro.core import SsspConfig, build_shards, solve_sim_batch
 from repro.core.faults import FaultPlan
 from repro.graph import dijkstra_reference, random_graph
 from repro.kernels.round import (fused_round_pallas, fused_round_ref,
@@ -257,22 +256,16 @@ def test_fused_round_bit_identical_shmap():
 # ------------------------------------------------- layout fallback ----
 
 def test_fused_round_falls_back_with_one_time_warning():
-    """Without the tiled layouts the fused backend degrades to the staged
-    pipeline (default xla phases) with exactly ONE warning, once."""
+    """Without the tiled layouts the fused backend refuses to run: it
+    raises, on every solve, instead of degrading to the staged pipeline."""
     g = random_graph(150, 600, seed=9)
     sh = build_shards(g, 4, relax_layout=False, comm_layout=False)
     cfg = SsspConfig(round="fused")
-    phases._WARNED.clear()
-    with warnings.catch_warnings(record=True) as rec:
-        warnings.simplefilter("always")
-        d, stats = solve_sim_batch(sh, [0], cfg)
-    msgs = [str(w.message) for w in rec]
-    assert len(msgs) == 1 and "round='fused' falling back" in msgs[0], msgs
+    for src in (0, 1):
+        with pytest.raises(ValueError, match="round='fused' needs"):
+            solve_sim_batch(sh, [src], cfg)
+    # the staged pipeline still serves the same shards
+    d, stats = solve_sim_batch(sh, [0], SsspConfig())
     np.testing.assert_allclose(d[0], dijkstra_reference(g, 0),
                                rtol=1e-5, atol=1e-4)
-    # the fallback really is the staged pipeline: 4 dispatches per round
     assert int(stats.n_dispatches) == 4 * int(stats.rounds)
-    with warnings.catch_warnings(record=True) as rec2:
-        warnings.simplefilter("always")
-        solve_sim_batch(sh, [1], cfg)
-    assert not rec2

@@ -31,7 +31,7 @@ PROG = textwrap.dedent("""
     def ring_prog():
         r = flat_rank(axes)
         return ring_permute(r, axes)
-    out = jax.jit(compat.shard_map(lambda: ring_prog()[None], mesh=mesh,
+    out = jax.jit(jax.shard_map(lambda: ring_prog()[None], mesh=mesh,
                                    in_specs=(), out_specs=P(axes),
                                    check_vma=False))()
     got = np.asarray(out)
@@ -71,7 +71,7 @@ PROG = textwrap.dedent("""
     rep = jax.NamedSharding(mesh, P())
     params, opt, batch = jax.device_put((params, opt, batch), rep)
     step = jax.jit(tf.make_train_step(cfg, ax, AdamWConfig()))
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         _, _, m = step(params, opt, batch)
     assert np.isfinite(float(m["loss"]))
     print("LM MESH OK")

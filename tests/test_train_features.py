@@ -3,7 +3,6 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from repro import compat
 from repro.distributed.sharding import MeshAxes
 from repro.models import transformer as tf
 from repro.models.params import materialize
@@ -22,7 +21,7 @@ def test_microbatched_step_matches_full_batch(mesh11):
     rng = np.random.default_rng(0)
     batch = {"tokens": jnp.asarray(rng.integers(0, 64, (4, 16))),
              "labels": jnp.asarray(rng.integers(0, 64, (4, 16)))}
-    with compat.set_mesh(mesh11):
+    with jax.set_mesh(mesh11):
         p1, _, m1 = jax.jit(tf.make_train_step(CFG, AX, AdamWConfig()))(
             params, opt, batch)
         p4, _, m4 = jax.jit(tf.make_train_step(CFG, AX, AdamWConfig(),
