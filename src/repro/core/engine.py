@@ -46,10 +46,12 @@ from __future__ import annotations
 
 import dataclasses
 import time
+from functools import partial
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation, annotate_function
 from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as P
 
@@ -232,17 +234,22 @@ class SsspEngine:
             comm = SimComm(shards.n_parts)
             n_parts = shards.n_parts
 
-            def counted_round(sh, carry):
+            # A program's name is part of JAX's compilation-cache key, its
+            # op_names are not: the names set the scoped round and
+            # certificate apart from unscoped executables cached by older
+            # versions, which would load without their phases. They also
+            # name the programs in a trace (``jit_sssp_round``).
+            def sssp_round(sh, carry):
                 self._note_trace(int(carry.dist.shape[1]))
                 return _make_round(sh, cfg, comm, vmapped=True,
                                    n_parts=n_parts)(carry)
 
-            def counted_cert(sh, dist_pk):
+            def sssp_certificate(sh, dist_pk):
                 self.cert_traces += 1
                 return certificate_improved_sim(sh, dist_pk)
 
-            self.round_fn = jax.jit(counted_round)
-            self._cert_fn = jax.jit(counted_cert)
+            self.round_fn = jax.jit(sssp_round)
+            self._cert_fn = jax.jit(sssp_certificate)
             # fused round / deferred (async) exchange: the loop can exit
             # with delivered-but-unmerged messages in carry.incoming and
             # undelivered payload in carry.inflight (see sssp.make_finalize)
@@ -339,11 +346,18 @@ class SsspEngine:
             return self._solve_batch(srcs, bucket=bucket)
         return self._solve_cached(srcs, bucket=bucket)
 
+    @partial(annotate_function, name="sssp.solve")
     def _solve_batch(self, srcs: tuple, *, bucket: bool = True,
                      use_warm: bool = True) -> QueryResult:
         """Run the compiled pipeline for ``srcs`` (no result-cache layer).
         ``use_warm=False`` forces the cold +inf init — used to solve the
-        landmark pivots themselves."""
+        landmark pivots themselves.
+
+        Each host step runs in a profiler span on the trace's clock
+        (``sssp.init``, ``sssp.round`` — ``sssp.compile`` while the bucket
+        has no program yet — ``sssp.sync``, ``sssp.finalize``,
+        ``sssp.copy_out``, ``sssp.stats``, ``sssp.certificate``); the spans
+        add no sync of their own."""
         k = len(srcs)
         kb = bucket_k(k) if bucket else k
         src_arr = np.zeros((kb,), np.int32)
@@ -356,61 +370,74 @@ class SsspEngine:
         t0 = time.perf_counter()
         compile_s = 0.0
         if self.backend == "sim":
-            seed = None
-            if warm:
-                tc = time.perf_counter()
-                seed = self._warm_seed(self.landmarks.dist,
-                                       jnp.asarray(src_arr),
-                                       jnp.asarray(q_valid))
-                if self.trace_count > traces0:
-                    jax.block_until_ready(seed)
-                    compile_s += time.perf_counter() - tc
-            if warm:
-                # solve-time coverage, keyed (bucket, L) like the shmap
-                # path: the seed program is separate from the round, so a
-                # cold trace of this bucket does not make the warm path
-                # compile-free (warmup() consults this set)
-                self._warm_traced.add((kb, self.landmarks.n_landmarks))
-            carry = _init_carry(self.shards, src_arr, self.cfg, rank=None,
-                                vmapped=True, q_valid=q_valid,
-                                seed_dist=seed)
+            with TraceAnnotation("sssp.init"):
+                seed = None
+                if warm:
+                    tc = time.perf_counter()
+                    seed = self._warm_seed(self.landmarks.dist,
+                                           jnp.asarray(src_arr),
+                                           jnp.asarray(q_valid))
+                    if self.trace_count > traces0:
+                        jax.block_until_ready(seed)
+                        compile_s += time.perf_counter() - tc
+                    # solve-time coverage, keyed (bucket, L) like the
+                    # shmap path: the seed program is separate from the
+                    # round, so a cold trace of this bucket does not make
+                    # the warm path compile-free (warmup() consults this)
+                    self._warm_traced.add((kb, self.landmarks.n_landmarks))
+                carry = _init_carry(self.shards, src_arr, self.cfg,
+                                    rank=None, vmapped=True, q_valid=q_valid,
+                                    seed_dist=seed)
             r = 0
             traces_loop = self.trace_count
             while r < self.cfg.max_rounds:
                 fresh = self.trace_count == traces_loop
                 tc = time.perf_counter()
-                carry = self.round_fn(self.shards, carry)
-                if fresh and self.trace_count > traces_loop:
-                    jax.block_until_ready(carry)
-                    compile_s += time.perf_counter() - tc
+                span = ("sssp.round" if self.trace_counts.get(kb)
+                        else "sssp.compile")
+                with TraceAnnotation(span):
+                    carry = self.round_fn(self.shards, carry)
+                    if fresh and self.trace_count > traces_loop:
+                        jax.block_until_ready(carry)
+                        compile_s += time.perf_counter() - tc
                 r += 1
-                if bool(np.asarray(carry.done).all()):
+                with TraceAnnotation("sssp.sync"):
+                    done = bool(np.asarray(carry.done).all())
+                if done:
                     break
             dist_pk = carry.dist
             if self._finalize_fn is not None:
-                dist_pk = self._finalize_fn(self.shards, carry)
-            done_k = np.asarray(carry.done)[0][:k]  # globally agreed
-            # [P, K, block] -> per-query global distance vectors
-            dist = np.moveaxis(np.asarray(dist_pk), 0, 1)
-            dist = dist.reshape(kb, -1)[:k, : self.shards.n_vertices]
+                with TraceAnnotation("sssp.finalize"):
+                    dist_pk = self._finalize_fn(self.shards, carry)
+            with TraceAnnotation("sssp.copy_out"):
+                done_k = np.asarray(carry.done)[0][:k]  # globally agreed
+                # [P, K, block] -> per-query global distance vectors
+                dist = np.moveaxis(np.asarray(dist_pk), 0, 1)
+                dist = dist.reshape(kb, -1)[:k, : self.shards.n_vertices]
             # host sums in int64: a K=16 batch at real graph sizes comes
             # near 2**31 relaxations
-            stats = SsspStats(
-                rounds=carry.rounds,
-                relaxations=np.sum(np.asarray(carry.relaxations), dtype=np.int64),
-                msgs_sent=np.sum(np.asarray(carry.msgs_sent), dtype=np.int64),
-                msgs_recv=np.sum(np.asarray(carry.msgs_recv), dtype=np.int64),
-                pruned_edges=np.sum(np.asarray(carry.pruned), dtype=np.int64),
-                q_rounds=np.max(np.asarray(carry.q_rounds), axis=0)[:k],
-                q_relaxations=np.sum(np.asarray(carry.relaxations),
-                                     axis=0)[:k],
-                stale_merges=np.sum(np.asarray(carry.stale), dtype=np.int64),
-                resends=np.sum(np.asarray(carry.resent), dtype=np.int64),
-                n_dispatches=np.int32(
-                    int(np.asarray(carry.rounds))
-                    * dispatches_per_round(self.shards, self.cfg)),
-                overlap_rounds=np.int32(np.asarray(carry.overlap)),
-                bytes_moved=np.int64(np.asarray(carry.comm_bytes)))
+            with TraceAnnotation("sssp.stats"):
+                stats = SsspStats(
+                    rounds=carry.rounds,
+                    relaxations=np.sum(np.asarray(carry.relaxations),
+                                       dtype=np.int64),
+                    msgs_sent=np.sum(np.asarray(carry.msgs_sent),
+                                     dtype=np.int64),
+                    msgs_recv=np.sum(np.asarray(carry.msgs_recv),
+                                     dtype=np.int64),
+                    pruned_edges=np.sum(np.asarray(carry.pruned),
+                                        dtype=np.int64),
+                    q_rounds=np.max(np.asarray(carry.q_rounds), axis=0)[:k],
+                    q_relaxations=np.sum(np.asarray(carry.relaxations),
+                                         axis=0)[:k],
+                    stale_merges=np.sum(np.asarray(carry.stale),
+                                        dtype=np.int64),
+                    resends=np.sum(np.asarray(carry.resent), dtype=np.int64),
+                    n_dispatches=np.int32(
+                        int(np.asarray(carry.rounds))
+                        * dispatches_per_round(self.shards, self.cfg)),
+                    overlap_rounds=np.int32(np.asarray(carry.overlap)),
+                    bytes_moved=np.int64(np.asarray(carry.comm_bytes)))
         else:
             tc = time.perf_counter()
             if warm:
@@ -433,9 +460,10 @@ class SsspEngine:
             jax.block_until_ready(dist_pk)
             if self.trace_count > traces0:
                 compile_s = time.perf_counter() - tc
-            done_k = np.asarray(stats.q_converged)[:k]
-            dist = np.moveaxis(np.asarray(dist_pk), 0, 1)   # [K, P, block]
-            dist = dist.reshape(kb, -1)[:k, : self.shards.n_vertices]
+            with TraceAnnotation("sssp.copy_out"):
+                done_k = np.asarray(stats.q_converged)[:k]
+                dist = np.moveaxis(np.asarray(dist_pk), 0, 1)  # [K, P, block]
+                dist = dist.reshape(kb, -1)[:k, : self.shards.n_vertices]
             stats = stats._replace(q_rounds=stats.q_rounds[:k],
                                    q_relaxations=stats.q_relaxations[:k])
 
@@ -445,17 +473,18 @@ class SsspEngine:
         # max_rounds at the fixpoint is converged, a detector that fired
         # over a dropped message is not.
         if self.certify:
-            if self.backend == "sim":
-                improved = np.asarray(self._cert_fn(self.shards,
-                                                    dist_pk))[:k]
-            else:
-                if self._cert_shmap is None:
-                    self._cert_shmap = build_shmap_certificate(
-                        self.shards, self.mesh, self.axis_names,
-                        on_trace=lambda _k: setattr(
-                            self, "cert_traces", self.cert_traces + 1))
-                improved = np.asarray(
-                    self._cert_shmap(self.shards, dist_pk))[:k]
+            with TraceAnnotation("sssp.certificate"):
+                if self.backend == "sim":
+                    improved = np.asarray(self._cert_fn(self.shards,
+                                                        dist_pk))[:k]
+                else:
+                    if self._cert_shmap is None:
+                        self._cert_shmap = build_shmap_certificate(
+                            self.shards, self.mesh, self.axis_names,
+                            on_trace=lambda _k: setattr(
+                                self, "cert_traces", self.cert_traces + 1))
+                    improved = np.asarray(
+                        self._cert_shmap(self.shards, dist_pk))[:k]
             q_conv = ~improved
         else:
             q_conv = done_k.copy()
@@ -649,6 +678,7 @@ class SsspEngine:
     def pending(self) -> int:
         return len(self._pending)
 
+    @partial(annotate_function, name="sssp.drain")
     def drain(self) -> list[QueryResult]:
         """Coalesce pending arrivals into bucketed batches and solve them.
 

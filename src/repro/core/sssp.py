@@ -206,6 +206,7 @@ def _phase_local(shard: SsspShards, dist, active, pruned, cursor, cfg: SsspConfi
             pallas_sweeps=cfg.pallas_sweeps)
         return res.dist, pruned, cursor, res.relaxations, jnp.int32(0)
 
+    @jax.named_scope("sssp.prune")
     def prune(dist, pruned, cursor):
         nrel0 = jnp.zeros((nq,), jnp.int32)
         if not cfg.prune_online:
@@ -910,6 +911,7 @@ def make_finalize(sh: SsspShards, cfg: SsspConfig, comm, vmapped: bool):
     else:
         merge = lambda dist, incoming: fin(sh, dist, incoming)
 
+    @jax.named_scope("sssp.finalize")
     def finalize(carry: _Carry):
         dist = carry.dist
         if fused:
@@ -1005,11 +1007,14 @@ def _make_round_fused(sh: SsspShards, cfg: SsspConfig, comm, vmapped: bool,
         # (monotone min merge), only round counts move.
         incoming_new = inflight_mid = delivering = None
         if deferred:
-            pend0 = _pending_inflight(carry.inflight, vmapped)
-            delivering = jnp.any(pend0, axis=-1)    # per-shard bool
-            incoming_new, inflight_mid = ex.recv(comm, carry.inflight)
+            with jax.named_scope("sssp.exchange"):
+                pend0 = _pending_inflight(carry.inflight, vmapped)
+                delivering = jnp.any(pend0, axis=-1)    # per-shard bool
+                incoming_new, inflight_mid = ex.recv(comm, carry.inflight)
 
-        pruned, cursor = prune_v(sh, idle, carry.pruned, carry.tri_cursor)
+        with jax.named_scope("sssp.prune"):
+            pruned, cursor = prune_v(sh, idle, carry.pruned,
+                                     carry.tri_cursor)
         # injected frontier (warm-start seeds / source bits on round 0;
         # zeroed by every fused round thereafter)
         front_in = carry.active & live[..., None]
@@ -1019,14 +1024,13 @@ def _make_round_fused(sh: SsspShards, cfg: SsspConfig, comm, vmapped: bool,
         resend_now = None
         last_in = carry.last_sent
         if fp is not None and fp.resend_period > 0:
-            period = jnp.int32(fp.resend_period)
-            period_hit = (carry.rounds % period) == (period - 1)
-            need = comm.all_any(carry.faults.unhealed)
-            resend_now = period_hit & need
-            last_in = jnp.where(resend_now[..., None], INF, carry.last_sent)
-
-        dist, payload, last_sent, sends, nrel, resid = fused_v(
-            sh, carry.dist, front_in, live, carry.incoming, last_in, pruned)
+            with jax.named_scope("sssp.send"):
+                period = jnp.int32(fp.resend_period)
+                period_hit = (carry.rounds % period) == (period - 1)
+                need = comm.all_any(carry.faults.unhealed)
+                resend_now = period_hit & need
+                last_in = jnp.where(resend_now[..., None], INF,
+                                    carry.last_sent)
 
         # rescue: predicate reduced over the WHOLE shard stack, so the sim
         # backend branches for real (an unbatched lax.cond) and the common
@@ -1040,45 +1044,57 @@ def _make_round_fused(sh: SsspShards, cfg: SsspConfig, comm, vmapped: bool,
             d, pl_, ls, sd, nr, _rs, _li, _pr = args
             return d, pl_, ls, sd, nr
 
-        dist, payload, last_sent, sends, nrel = lax.cond(
-            jnp.any(resid > 0), rescue, keep,
-            (dist, payload, last_sent, sends, nrel, resid, last_in, pruned))
+        with jax.named_scope("sssp.fused"):
+            dist, payload, last_sent, sends, nrel, resid = fused_v(
+                sh, carry.dist, front_in, live, carry.incoming, last_in,
+                pruned)
+            dist, payload, last_sent, sends, nrel = lax.cond(
+                jnp.any(resid > 0), rescue, keep,
+                (dist, payload, last_sent, sends, nrel, resid, last_in,
+                 pruned))
 
-        payload, nbytes = _mask_payload(payload)
-        if deferred:
-            inflight = ex.push(comm, inflight_mid, payload)
-        else:
-            incoming_new = ex.run(comm, payload)
-            inflight = carry.inflight
+        with jax.named_scope("sssp.send"):
+            payload, nbytes = _mask_payload(payload)
+        with jax.named_scope("sssp.exchange"):
+            if deferred:
+                inflight = ex.push(comm, inflight_mid, payload)
+            else:
+                incoming_new = ex.run(comm, payload)
+                inflight = carry.inflight
 
         fstate, stale, pending = carry.faults, None, None
         if deliver_f is not None:
-            if resend_now is not None:
-                fstate = fstate._replace(
-                    unhealed=jnp.where(resend_now, False, fstate.unhealed))
-            rkey = jax.random.fold_in(jax.random.PRNGKey(fp.seed),
-                                      carry.rounds)
-            rank = comm.rank()
-            if vmapped:
-                keys = jax.vmap(lambda r: jax.random.fold_in(rkey, r))(rank)
-            else:
-                keys = jax.random.fold_in(rkey, rank)
-            incoming_new, fstate, stale, pending = deliver_f(
-                sh, dist, incoming_new, fstate, keys)
+            with jax.named_scope("sssp.deliver"):
+                if resend_now is not None:
+                    fstate = fstate._replace(
+                        unhealed=jnp.where(resend_now, False,
+                                           fstate.unhealed))
+                rkey = jax.random.fold_in(jax.random.PRNGKey(fp.seed),
+                                          carry.rounds)
+                rank = comm.rank()
+                if vmapped:
+                    keys = jax.vmap(
+                        lambda r: jax.random.fold_in(rkey, r))(rank)
+                else:
+                    keys = jax.random.fold_in(rkey, rank)
+                incoming_new, fstate, stale, pending = deliver_f(
+                    sh, dist, incoming_new, fstate, keys)
 
-        any_imp, recvs, n_imp = account_v(sh, dist, incoming_new)
+        with jax.named_scope("sssp.merge"):
+            any_imp, recvs, n_imp = account_v(sh, dist, incoming_new)
 
         # the detectors only consume any(new_active, -1), so a synthetic
         # [.., K, 1] mask carrying the any-improvement bit is equivalent
         # to the staged merge's full frontier plane
-        toka_flag = any_imp
-        if pending is not None:
-            toka_flag = toka_flag | pending
-        if deferred:
-            toka_flag = toka_flag | _pending_inflight(inflight, vmapped)
-        done, toka2, streak = toka_f(
-            cfg, comm, carry, toka_flag[..., None], sends, recvs,
-            sh.inter_edges, n_parts, comm.rank(), vmapped)
+        with jax.named_scope("sssp.toka"):
+            toka_flag = any_imp
+            if pending is not None:
+                toka_flag = toka_flag | pending
+            if deferred:
+                toka_flag = toka_flag | _pending_inflight(inflight, vmapped)
+            done, toka2, streak = toka_f(
+                cfg, comm, carry, toka_flag[..., None], sends, recvs,
+                sh.inter_edges, n_parts, comm.rank(), vmapped)
 
         stale_c, resent_c = carry.stale, carry.resent
         if deferred:
@@ -1147,15 +1163,17 @@ def _make_round(shard_or_stack: SsspShards, cfg: SsspConfig, comm, vmapped: bool
         # shard's compute and the delivery of its neighbors' messages
         incoming = inflight_mid = delivering = None
         if deferred:
-            pend0 = _pending_inflight(carry.inflight, vmapped)
-            delivering = jnp.any(pend0, axis=-1)    # per-shard bool
-            incoming, inflight_mid = ex.recv(comm, carry.inflight)
+            with jax.named_scope("sssp.exchange"):
+                pend0 = _pending_inflight(carry.inflight, vmapped)
+                delivering = jnp.any(pend0, axis=-1)    # per-shard bool
+                incoming, inflight_mid = ex.recv(comm, carry.inflight)
 
         # converged-query mask: finished queries stop relaxing and sending
         # while stragglers run (their frontier is forced empty)
         act = carry.active & ~carry.done[..., None]
-        dist, pruned, cursor, nrel, nprune = local_f(
-            sh, carry.dist, act, carry.pruned, carry.tri_cursor)
+        with jax.named_scope("sssp.local"):
+            dist, pruned, cursor, nrel, nprune = local_f(
+                sh, carry.dist, act, carry.pruned, carry.tri_cursor)
 
         # anti-entropy: every resend_period-th round, senders forget their
         # last_sent floor for any query some receiver reported an unhealed
@@ -1170,61 +1188,68 @@ def _make_round(shard_or_stack: SsspShards, cfg: SsspConfig, comm, vmapped: bool
         # toka3's streak forever.
         resend_now = None
         last_in = carry.last_sent
-        if fp is not None and fp.resend_period > 0:
-            period = jnp.int32(fp.resend_period)
-            period_hit = (carry.rounds % period) == (period - 1)
-            need = comm.all_any(carry.faults.unhealed)   # [K] ([P, K] sim)
-            resend_now = period_hit & need
-            last_in = jnp.where(resend_now[..., None], INF, carry.last_sent)
-
-        payload, last_sent, sends = send_f(sh, dist, pruned, last_in)
-        payload, nbytes = _mask_payload(payload)
-        if deferred:
-            inflight = ex.push(comm, inflight_mid, payload)
-        else:
-            incoming = ex.run(comm, payload)
-            inflight = carry.inflight
+        with jax.named_scope("sssp.send"):
+            if fp is not None and fp.resend_period > 0:
+                period = jnp.int32(fp.resend_period)
+                period_hit = (carry.rounds % period) == (period - 1)
+                need = comm.all_any(carry.faults.unhealed)  # [K] ([P, K] sim)
+                resend_now = period_hit & need
+                last_in = jnp.where(resend_now[..., None], INF,
+                                    carry.last_sent)
+            payload, last_sent, sends = send_f(sh, dist, pruned, last_in)
+            payload, nbytes = _mask_payload(payload)
+        with jax.named_scope("sssp.exchange"):
+            if deferred:
+                inflight = ex.push(comm, inflight_mid, payload)
+            else:
+                incoming = ex.run(comm, payload)
+                inflight = carry.inflight
 
         fstate, stale, pending = carry.faults, None, None
         if deliver_f is not None:
-            if resend_now is not None:
-                # this resend round retransmits everything: clear the
-                # unhealed latch BEFORE injection so only drops of the
-                # resent copies themselves re-arm it
-                fstate = fstate._replace(
-                    unhealed=jnp.where(resend_now, False, fstate.unhealed))
-            rkey = jax.random.fold_in(jax.random.PRNGKey(fp.seed),
-                                      carry.rounds)
-            rank = comm.rank()
-            if vmapped:
-                keys = jax.vmap(lambda r: jax.random.fold_in(rkey, r))(rank)
-            else:
-                keys = jax.random.fold_in(rkey, rank)
-            incoming, fstate, stale, pending = deliver_f(
-                sh, dist, incoming, fstate, keys)
+            with jax.named_scope("sssp.deliver"):
+                if resend_now is not None:
+                    # this resend round retransmits everything: clear the
+                    # unhealed latch BEFORE injection so only drops of the
+                    # resent copies themselves re-arm it
+                    fstate = fstate._replace(
+                        unhealed=jnp.where(resend_now, False,
+                                           fstate.unhealed))
+                rkey = jax.random.fold_in(jax.random.PRNGKey(fp.seed),
+                                          carry.rounds)
+                rank = comm.rank()
+                if vmapped:
+                    keys = jax.vmap(
+                        lambda r: jax.random.fold_in(rkey, r))(rank)
+                else:
+                    keys = jax.random.fold_in(rkey, rank)
+                incoming, fstate, stale, pending = deliver_f(
+                    sh, dist, incoming, fstate, keys)
 
         stale_async = None
-        if deferred:
-            # improving entries of the FINAL delivered batch (post fault
-            # injection) against the pre-merge distances: under a lagged
-            # delivery every improving merge is by definition stale
-            stale_async = stale_f(sh, dist, incoming)
-
-        dist, new_active, recvs = merge_f(sh, dist, incoming)
+        with jax.named_scope("sssp.merge"):
+            if deferred:
+                # improving entries of the FINAL delivered batch (post
+                # fault injection) against the pre-merge distances: under
+                # a lagged delivery every improving merge is by definition
+                # stale
+                stale_async = stale_f(sh, dist, incoming)
+            dist, new_active, recvs = merge_f(sh, dist, incoming)
 
         # termination sees pending in-flight state as activity; the real
         # frontier stays clean (a fake frontier bit would cause spurious
         # relaxation work, not just a held-open detector)
-        pend_bits = pending
-        if deferred:
-            ab = _pending_inflight(inflight, vmapped)
-            pend_bits = ab if pend_bits is None else (pend_bits | ab)
-        toka_active = new_active
-        if pend_bits is not None:
-            toka_active = new_active | pend_bits[..., None]
-        done, toka2, streak = pipe.toka(
-            cfg, comm, carry, toka_active, sends, recvs, sh.inter_edges,
-            n_parts, comm.rank(), vmapped)
+        with jax.named_scope("sssp.toka"):
+            pend_bits = pending
+            if deferred:
+                ab = _pending_inflight(inflight, vmapped)
+                pend_bits = ab if pend_bits is None else (pend_bits | ab)
+            toka_active = new_active
+            if pend_bits is not None:
+                toka_active = new_active | pend_bits[..., None]
+            done, toka2, streak = pipe.toka(
+                cfg, comm, carry, toka_active, sends, recvs, sh.inter_edges,
+                n_parts, comm.rank(), vmapped)
 
         stale_c, resent_c = carry.stale, carry.resent
         if stale_async is not None:
@@ -1261,11 +1286,12 @@ def _make_round(shard_or_stack: SsspShards, cfg: SsspConfig, comm, vmapped: bool
 
 
 def sim_phase_fns(sh: SsspShards, cfg: SsspConfig):
-    """Jitted per-phase callables over the stacked sim representation —
-    the per-phase attribution hook for benchmarks: each phase of the round
-    (local / send / exchange / merge) can be driven and timed in isolation
-    on real mid-solve state. Shapes follow the sim carry convention
-    (leading [P], then [K])."""
+    """Jitted per-phase callables over the stacked sim representation:
+    each phase of the round (local / send / exchange / merge, and the
+    fused megakernel where the shards carry its layouts) can be driven in
+    isolation, e.g. to read its Pallas grid. Shapes follow the sim carry
+    convention (leading [P], then [K]). Device time per phase comes from
+    the round's ``jax.named_scope`` phases in a profiler trace."""
     comm = SimComm(sh.n_parts)
     pipe = build_pipeline(sh, cfg)
     fns = {
@@ -1294,6 +1320,7 @@ def _toka2_init_batch(rank, nq: int):
         lambda x: jnp.broadcast_to(x, (nq,) + jnp.shape(x)), st)
 
 
+@jax.named_scope("sssp.init")
 def _init_carry(sh: SsspShards, sources, cfg: SsspConfig, rank,
                 vmapped: bool, q_valid=None, seed_dist=None):
     """Stacked init (sim) or per-shard init (shard_map) for K sources.
@@ -1451,6 +1478,7 @@ def _cert_relax_shard(shard: SsspShards, dist):
     return new, _scatter_dense(shard, slot_val, dist.shape[1])
 
 
+@jax.named_scope("sssp.certificate")
 def certificate_improved_sim(sh: SsspShards, dist):
     """Certificate over the stacked sim state: ``dist`` [P, K, block] ->
     ``improved`` [K] bool (True = NOT at the fixpoint)."""
@@ -1470,6 +1498,7 @@ def build_shmap_certificate(sh_spec: SsspShards, mesh, axis_names,
     axes = tuple(axis_names)
     comm = ShmapComm(axes)
 
+    @jax.named_scope("sssp.certificate")
     def body(sh_local: SsspShards, dist_loc):
         sh1 = jax.tree_util.tree_map(lambda x: x[0], sh_local)
         d = dist_loc[0]
