@@ -337,11 +337,15 @@ def _sssp_abstract_shards(gspec, n_parts: int):
         cut_seg=SDS((Pn, s["e_cut"]), i32),
         slot_owner=SDS((Pn, s["S"]), i32), slot_dstl=SDS((Pn, s["S"]), i32),
         slot_pos=SDS((Pn, s["S"]), i32), slot_valid=SDS((Pn, s["S"]), b_),
+        slot_last=SDS((Pn, s["S"]), i32),
         recv_idx=SDS((Pn, Pn, s["C"]), i32),
+        tx_payload_slot=SDS((Pn, Pn, s["C"]), i32),
         tri_uj=SDS((Pn, s["T"]), i32), tri_ui=SDS((Pn, s["T"]), i32),
         tri_ij=SDS((Pn, s["T"]), i32), tri_valid=SDS((Pn, s["T"]), b_),
         inter_edges=SDS((Pn,), i32),
         n_vertices=gspec.n_vertices, n_parts=Pn, block=s["block"],
+        # S slots over e_cut edges: no run is longer than e_cut - S + 1
+        seg_steps=(s["e_cut"] - s["S"]).bit_length(),
     )
 
 

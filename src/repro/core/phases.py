@@ -2,7 +2,7 @@
 
 The outer round is a fixed sequence of phases — local solve, send pack,
 exchange, merge, termination — but each phase has interchangeable
-*backends* (e.g. the send pack can run as XLA ``segment_min`` or as the
+*backends* (e.g. the send pack can run as an XLA segmented min or as the
 slot-tiled Pallas kernel). This module is the small registry that maps
 ``(phase, backend_name) -> implementation`` so:
 

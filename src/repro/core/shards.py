@@ -82,8 +82,13 @@ class SsspShards:
     slot_dstl: jax.Array   # [P, S] int32 dst-local id on the destination shard
     slot_pos: jax.Array    # [P, S] int32 position within the [P, C] send row
     slot_valid: jax.Array  # [P, S] bool
+    slot_last: jax.Array   # [P, S] int32 index of the slot's last cut edge
+    #                        (0 for padded slots)
     # receive routing: local vertex addressed by (sender, bucket position)
     recv_idx: jax.Array    # [P, P, C] int32 (block = invalid sentinel)
+    # static inverse of (slot_owner, slot_pos): the slot feeding each
+    # bucketed payload position, so the payload scatter becomes a gather
+    tx_payload_slot: jax.Array  # [P, P, C] int32 (sentinel = S)
     # Trishla triangle candidates: edge-id triples (uj to prune, ui, ij)
     tri_uj: jax.Array      # [P, T] int32 -> index into the *combined* edge view
     tri_ui: jax.Array      # [P, T] int32
@@ -94,6 +99,9 @@ class SsspShards:
     n_vertices: int = dataclasses.field(metadata=dict(static=True))
     n_parts: int = dataclasses.field(metadata=dict(static=True))
     block: int = dataclasses.field(metadata=dict(static=True))
+    # ceil(log2(longest run of one slot's cut edges)) over every shard's
+    # real slots: the doubling steps of the send pack's segmented min
+    seg_steps: int = dataclasses.field(metadata=dict(static=True))
     # dst-tiled layout of the LOCAL edges for the Pallas relax kernel
     # (built once at partition time; None when relax_layout=False). The
     # tiled slots are a permutation of [0, e_loc) plus padding; rx_eid maps
@@ -118,9 +126,6 @@ class SsspShards:
     tx_segrel: jax.Array | None = None
     tx_eid: jax.Array | None = None
     tx_ctile: jax.Array | None = None   # [P, total_chunks] int32 (ragged only)
-    # static inverse of (slot_owner, slot_pos): the slot feeding each
-    # bucketed payload position, so the payload scatter becomes a gather
-    tx_payload_slot: jax.Array | None = None  # [P, P, C] int32 (sentinel = S)
     tx_sb: int = dataclasses.field(default=128, metadata=dict(static=True))
     tx_eb: int = dataclasses.field(default=512, metadata=dict(static=True))
     # msg-tiled receive routing for the Pallas merge kernel: flat incoming
@@ -445,8 +450,9 @@ def _assemble_shards(parts, n, P, block, *, max_triangles_per_part,
 
     loc_rows_src, loc_rows_dst, loc_rows_w = [], [], []
     cut_rows_src, cut_rows_w, cut_rows_seg = [], [], []
-    slot_rows_owner, slot_rows_dstl = [], []
+    slot_rows_owner, slot_rows_dstl, slot_rows_last = [], [], []
     inter_edges = np.zeros(P, np.int64)
+    longest_run = 1
 
     for p in range(P):
         p_src, p_do, p_dl, p_w = parts[p]
@@ -466,15 +472,20 @@ def _assemble_shards(parts, n, P, block, *, max_triangles_per_part,
             seg_id = np.cumsum(new_seg) - 1
             u_owner = co[new_seg]
             u_dstl = cl[new_seg]
+            last = np.append(np.flatnonzero(new_seg[1:]), len(key) - 1)
+            longest_run = max(longest_run,
+                              int(np.diff(last, prepend=-1).max()))
         else:
             seg_id = np.zeros(0, np.int64)
             u_owner = np.zeros(0, np.int64)
             u_dstl = np.zeros(0, np.int64)
+            last = np.zeros(0, np.int64)
         cut_rows_src.append(cs)
         cut_rows_w.append(cw)
         cut_rows_seg.append(seg_id)
         slot_rows_owner.append(u_owner)
         slot_rows_dstl.append(u_dstl)
+        slot_rows_last.append(last)
         inter_edges[p] = int(cm.sum())
 
     e_loc = max(max((len(r) for r in loc_rows_src), default=0), 1)
@@ -499,6 +510,14 @@ def _assemble_shards(parts, n, P, block, *, max_triangles_per_part,
     for p in range(P):
         owners, dstl, pos = slot_rows_owner[p], slot_rows_dstl[p], slot_pos_rows[p]
         recv_idx[owners, p, pos] = dstl
+
+    # payload-position inverse: each (owner, pos) receives at most one
+    # slot, so the runtime [P, C] payload scatter becomes a gather
+    # (sentinel = S, out of the [0, S) slot range -> filled with +inf)
+    tx_payload_slot = np.full((P, P, C), S, np.int64)
+    for p in range(P):
+        owners, pos = slot_rows_owner[p], slot_pos_rows[p]
+        tx_payload_slot[p, owners, pos] = np.arange(len(owners))
 
     # ---- Trishla triangle candidates (host-side enumeration) --------------
     # Combined per-shard edge view: local edges [0, e_loc) then cut edges
@@ -640,8 +659,7 @@ def _assemble_shards(parts, n, P, block, *, max_triangles_per_part,
     # count (slots padded to S / vertices to block are shard-uniform) but
     # can differ in chunk count; pad to the max so they stack to [P, ...].
     comm = dict(tx_src=None, tx_w=None, tx_segrel=None, tx_eid=None,
-                tx_payload_slot=None, mx_pos=None, mx_dstrel=None,
-                mx_valid=None)
+                mx_pos=None, mx_dstrel=None, mx_valid=None)
     if comm_layout and layout == "ragged":
         per_shard = []
         for p in range(P):
@@ -670,11 +688,6 @@ def _assemble_shards(parts, n, P, block, *, max_triangles_per_part,
             tx_eid[p, :nc] = eid
             tx_ctile[p, :nc] = ct_r
 
-        tx_payload_slot = np.full((P, P, C), S, np.int64)
-        for p in range(P):
-            owners, pos = slot_rows_owner[p], slot_pos_rows[p]
-            tx_payload_slot[p, owners, pos] = np.arange(len(owners))
-
         mx_shards = [build_msg_ragged_layout(recv_idx[q], block, vb=merge_vb,
                                              eb=merge_eb) for q in range(P)]
         n_mtiles = mx_shards[0][4] // merge_vb
@@ -694,7 +707,6 @@ def _assemble_shards(parts, n, P, block, *, max_triangles_per_part,
                     tx_w=jnp.asarray(tx_w, jnp.float32),
                     tx_segrel=jnp.asarray(tx_segrel, jnp.int32),
                     tx_eid=jnp.asarray(tx_eid, jnp.int32),
-                    tx_payload_slot=jnp.asarray(tx_payload_slot, jnp.int32),
                     tx_ctile=jnp.asarray(tx_ctile, jnp.int32),
                     mx_pos=jnp.asarray(mx_pos, jnp.int32),
                     mx_dstrel=jnp.asarray(mx_dstrel, jnp.int32),
@@ -725,14 +737,6 @@ def _assemble_shards(parts, n, P, block, *, max_triangles_per_part,
             eid[eid == len(cut_rows_src[p])] = e_cut
             tx_eid[p, :, :nc] = eid
 
-        # payload-position inverse: each (owner, pos) receives at most one
-        # slot, so the runtime [P, C] payload scatter becomes a gather
-        # (sentinel = S, out of the [0, S) slot range -> filled with +inf)
-        tx_payload_slot = np.full((P, P, C), S, np.int64)
-        for p in range(P):
-            owners, pos = slot_rows_owner[p], slot_pos_rows[p]
-            tx_payload_slot[p, owners, pos] = np.arange(len(owners))
-
         mx_shards = [build_msg_tiled_layout(recv_idx[q], block, vb=merge_vb,
                                             eb=merge_eb) for q in range(P)]
         n_mtiles = mx_shards[0][3] // merge_vb
@@ -750,7 +754,6 @@ def _assemble_shards(parts, n, P, block, *, max_triangles_per_part,
                     tx_w=jnp.asarray(tx_w, jnp.float32),
                     tx_segrel=jnp.asarray(tx_segrel, jnp.int32),
                     tx_eid=jnp.asarray(tx_eid, jnp.int32),
-                    tx_payload_slot=jnp.asarray(tx_payload_slot, jnp.int32),
                     mx_pos=jnp.asarray(mx_pos, jnp.int32),
                     mx_dstrel=jnp.asarray(mx_dstrel, jnp.int32),
                     mx_valid=jnp.asarray(mx_valid, jnp.int32))
@@ -766,7 +769,9 @@ def _assemble_shards(parts, n, P, block, *, max_triangles_per_part,
         slot_dstl=jnp.asarray(_pad2(slot_rows_dstl, S, 0, np.int64), jnp.int32),
         slot_pos=jnp.asarray(_pad2(slot_pos_rows, S, 0, np.int64), jnp.int32),
         slot_valid=jnp.asarray(_pad2([np.ones(len(r), bool) for r in slot_rows_owner], S, False, bool)),
+        slot_last=jnp.asarray(_pad2(slot_rows_last, S, 0, np.int64), jnp.int32),
         recv_idx=jnp.asarray(recv_idx, jnp.int32),
+        tx_payload_slot=jnp.asarray(tx_payload_slot, jnp.int32),
         tri_uj=jnp.asarray(tri_uj, jnp.int32),
         tri_ui=jnp.asarray(tri_ui, jnp.int32),
         tri_ij=jnp.asarray(tri_ij, jnp.int32),
@@ -775,6 +780,7 @@ def _assemble_shards(parts, n, P, block, *, max_triangles_per_part,
         n_vertices=n,
         n_parts=P,
         block=block,
+        seg_steps=(longest_run - 1).bit_length(),
         layout=layout,
         rx_vb=relax_vb,
         rx_eb=relax_eb,

@@ -17,7 +17,7 @@ registry in ``core/phases.py``, keyed by ``SsspConfig`` — so backends
 compose freely (e.g. ``local_solver="pallas", send_backend="pallas",
 merge_backend="xla"``) in both the sim and shmap drivers, and new stages
 slot in without touching the loop. The send and merge phases each have an
-``xla`` backend (generic ``segment_min`` / ``at[].min``) and a ``pallas``
+``xla`` backend (a scatter-free segmented min / ``at[].min``) and a ``pallas``
 backend (the slot-tiled ``kernels/send`` pack and msg-tiled
 ``kernels/merge`` scatter, over layouts precomputed by ``build_shards``).
 
@@ -223,32 +223,52 @@ def _phase_local(shard: SsspShards, dist, active, pruned, cursor, cfg: SsspConfi
 def _scatter_dense(shard: SsspShards, send_val, blk: int):
     """Masked slot values -> dense [K, P, block] candidate rows addressed
     by (owner, dst_local). Shared by both send backends: the dense payload
-    is bandwidth-bound assembly, not a reduction — there is nothing for a
-    kernel to win (the segment-min upstream of it is the hot part)."""
+    is bandwidth-bound assembly over S slots, not a reduction over the
+    cut edges — there is nothing for a kernel to win."""
     Pn = shard.recv_idx.shape[0]
     return jax.vmap(
         lambda v: jnp.full((Pn, blk), INF, jnp.float32)
         .at[shard.slot_owner, shard.slot_dstl].min(v))(send_val)
 
 
+def _slot_min(shard: SsspShards, cand):
+    """Per-slot min of the cut-edge candidates ``cand`` [K, e_cut] -> [K, S],
+    +inf on padded slots.
+
+    The cut edges are sorted by slot, so each slot's candidates are one
+    contiguous run of ``cut_seg``. A segmented inclusive min-scan by
+    log-step doubling (``shard.seg_steps`` steps: shift right by k, keep the
+    min where position i - k lies in the same run) leaves each run's min at
+    its last edge, which one gather at ``slot_last`` reads out. No scatter:
+    each step is one elementwise pass in the candidates' own layout. Min is
+    exact, so the result is bit-identical to ``segment_min``."""
+    seg = shard.cut_seg
+    m = cand
+    for step in range(shard.seg_steps):
+        k = 1 << step
+        same = seg == jnp.pad(seg[:-k], (k, 0), constant_values=-1)
+        prev = jnp.pad(m[:, :-k], ((0, 0), (k, 0)), constant_values=INF)
+        m = jnp.where(same, jnp.minimum(m, prev), m)
+    return jnp.where(shard.slot_valid,
+                     jnp.take(m, shard.slot_last, axis=1), INF)
+
+
 @phases.register("send", "xla")
 def _phase_send_xla(shard: SsspShards, dist, pruned, last_sent, *,
                     dense: bool, cfg: SsspConfig):
-    """Generic XLA pack: per-slot ``segment_min`` + improvement masking.
+    """Generic XLA pack: gather of the cut edges' candidates, the per-slot
+    min by doubling over the slot-sorted edges (``_slot_min``), the
+    improvement masking, and the bucketed payload as a static gather
+    (``tx_payload_slot``). No scatter, except the dense payload's.
 
     Returns (payload [K, P, C] (bucket) or [K, P, block] (dense),
     last_sent' [K, S], sends [K])."""
     e_loc = shard.loc_src.shape[0]
-    S = shard.slot_owner.shape[0]
-    Pn, C = shard.recv_idx.shape[0], shard.recv_idx.shape[1]
-
     w_cut = jnp.where(pruned[e_loc:], INF, shard.cut_w)            # [e_cut]
     d_src = jnp.take(dist, shard.cut_src, axis=1, mode="fill",
                      fill_value=float("inf"))                      # [K, e_cut]
-    cand = d_src + w_cut
-    slot_val = jax.vmap(lambda c: jax.ops.segment_min(
-        c, shard.cut_seg, num_segments=S, indices_are_sorted=True))(cand)
-    improved = shard.slot_valid & (slot_val < last_sent)           # [K, S]
+    slot_val = _slot_min(shard, d_src + w_cut)                     # [K, S]
+    improved = shard.slot_valid & (slot_val < last_sent)
     send_val = jnp.where(improved, slot_val, INF)
     new_last = jnp.where(improved, slot_val, last_sent)
     sends = jnp.sum(improved, axis=-1).astype(jnp.int32)           # [K]
@@ -256,9 +276,7 @@ def _phase_send_xla(shard: SsspShards, dist, pruned, last_sent, *,
     if dense:
         payload = _scatter_dense(shard, send_val, dist.shape[1])
     else:
-        payload = jax.vmap(
-            lambda v: jnp.full((Pn, C), INF, jnp.float32)
-            .at[shard.slot_owner, shard.slot_pos].min(v))(send_val)
+        payload = send_payload_bucket(send_val, shard.tx_payload_slot)
     return payload, new_last, sends
 
 
@@ -1464,17 +1482,13 @@ def _cert_relax_shard(shard: SsspShards, dist):
     Returns (new_local [K, block] after local-edge relaxation, dense cut
     payload [K, P, block]); the caller min-combines the exchanged payloads
     with the local result and compares against ``dist``."""
-    S = shard.slot_owner.shape[0]
     d_src = jnp.take(dist, shard.loc_src, axis=1, mode="fill",
                      fill_value=float("inf"))
     new = jax.vmap(lambda d, c: d.at[shard.loc_dst].min(c, mode="drop"))(
         dist, d_src + shard.loc_w)
     d_cut = jnp.take(dist, shard.cut_src, axis=1, mode="fill",
                      fill_value=float("inf"))
-    slot_val = jax.vmap(lambda c: jax.ops.segment_min(
-        c, shard.cut_seg, num_segments=S,
-        indices_are_sorted=True))(d_cut + shard.cut_w)
-    slot_val = jnp.where(shard.slot_valid, slot_val, INF)
+    slot_val = _slot_min(shard, d_cut + shard.cut_w)
     return new, _scatter_dense(shard, slot_val, dist.shape[1])
 
 

@@ -3,9 +3,10 @@
 The send phase reduces every shard's cut-edge candidates ``dist[src] + w``
 to ONE value per message slot (a slot = a unique boundary pair
 ``(dst_owner, dst_local)``), masks the result against ``last_sent`` so only
-improvements transmit, and counts the sends. The XLA realization is a
-``segment_min`` — a sorted scatter with no efficient TPU lowering (the
-same gap the relax kernel closed for the local phase).
+improvements transmit, and counts the sends. The XLA backend does the
+per-slot min without a scatter, by log-step doubling over the slot-sorted
+cut edges (``core/sssp.py:_slot_min``); this kernel is the tiled
+alternative, which the v5e compiler refuses.
 
 TPU adaptation, following ``kernels/relax``'s dst-tiled pattern with the
 SLOT axis in the destination role: cut edges are pre-grouped by slot tile

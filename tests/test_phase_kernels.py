@@ -16,15 +16,20 @@ import os
 import subprocess
 import sys
 import textwrap
+import types
 
 import numpy as np
+import jax
 import jax.numpy as jnp
 import pytest
 
 from _hyp import given, settings, strategies as st
-from repro.core import (SsspConfig, build_shards, phases, sim_phase_fns,
-                        solve_sim_batch)
-from repro.graph import dijkstra_reference, random_graph
+from repro.core import (SsspConfig, SsspEngine, build_shards,
+                        build_shards_stream, certificate_improved_sim, phases,
+                        sim_phase_fns, solve_sim_batch)
+from repro.core.sssp import _cert_relax_shard, _slot_min
+from repro.graph import dijkstra_reference, edge_chunks_of, random_graph
+from repro.graph.structure import csr_from_coo
 from repro.kernels.merge import (build_msg_tiled_layout, merge_scatter_pallas,
                                  merge_scatter_ref)
 from repro.kernels.send import (build_slot_tiled_layout, send_pack_pallas,
@@ -129,6 +134,136 @@ def test_payload_gather_matches_scatter():
             np.minimum.at(ref[k], (owner, pos), val[k])
         got = send_payload_bucket(jnp.asarray(val), sh.tx_payload_slot[p])
         np.testing.assert_array_equal(np.asarray(got), ref)
+
+
+# ------------------------------------------- scatter-free slot min ----
+
+def _zipf_runs(rng, n):
+    return np.minimum(rng.zipf(1.6, size=n), 300)
+
+
+# (run lengths, padded tail of cut_seg == S, padded slots past the real)
+_SLOT_CASES = {
+    "runs_of_one": lambda rng: (np.ones(37, np.int64), 0, 3),
+    "one_run": lambda rng: (np.array([53]), 0, 2),
+    "power_law": lambda rng: (_zipf_runs(rng, 60), 5, 4),
+    "no_real_edge": lambda rng: (np.zeros(0, np.int64), 1, 1),
+    "padded_tail": lambda rng: (np.array([3, 1, 7, 2, 9]), 11, 0),
+}
+
+
+@pytest.mark.parametrize("nq", [1, 3, 16])
+@pytest.mark.parametrize("case", sorted(_SLOT_CASES))
+def test_slot_min_matches_segment_min(case, nq):
+    """The doubling slot min equals ``segment_min`` bit for bit, +inf
+    included: unreached sources, padded slots, the padded edge tail."""
+    rng = np.random.default_rng(sorted(_SLOT_CASES).index(case) * 31 + nq)
+    runs, tail, pad_slots = _SLOT_CASES[case](rng)
+    n_real = len(runs)
+    S = max(n_real + pad_slots, 1)
+    seg = np.concatenate([np.repeat(np.arange(n_real), runs),
+                          np.full(tail, S)]).astype(np.int32)
+    e_cut = len(seg)
+    cand = rng.uniform(0, 40, size=(nq, e_cut)).astype(np.float32)
+    cand[rng.random((nq, e_cut)) < 0.3] = np.inf
+    cand[:, seg == S] = np.inf
+    slot_last = np.zeros(S, np.int32)
+    slot_last[:n_real] = np.cumsum(runs) - 1
+    longest = int(runs.max()) if n_real else 1
+    shard = types.SimpleNamespace(
+        cut_seg=jnp.asarray(seg), slot_last=jnp.asarray(slot_last),
+        slot_valid=jnp.asarray(np.arange(S) < n_real),
+        seg_steps=int(np.ceil(np.log2(longest))))
+    got = np.asarray(_slot_min(shard, jnp.asarray(cand)))
+    ref = np.asarray(jax.vmap(lambda c: jax.ops.segment_min(
+        c, jnp.asarray(seg), num_segments=S, indices_are_sorted=True))(
+            jnp.asarray(cand)))
+    assert got.shape == (nq, S)
+    assert np.array_equal(got, ref)
+
+
+def _star_graph():
+    """64 vertices, 4 shards of 16: 13 sources on shard 0 and 5 on shard 1
+    all point at hub 63 (shard 3), plus a ring. Shard 0's run into the
+    hub is the longest: ceil(log2(13)) = 4 doubling steps."""
+    n = 64
+    src = np.concatenate([np.arange(13), 16 + np.arange(5), np.arange(n)])
+    dst = np.concatenate([np.full(18, 63), (np.arange(n) + 1) % n])
+    w = np.random.default_rng(2).uniform(1, 20, len(src)).astype(np.float32)
+    return csr_from_coo(src, dst, w, n)
+
+
+def _build(g, builder, layout, parts):
+    if builder == "batch":
+        return build_shards(g, parts, layout=layout)
+    return build_shards_stream(edge_chunks_of(g, chunk_edges=97),
+                               g.n_vertices, parts, layout=layout)
+
+
+@pytest.mark.parametrize("graph", ["random", "star"])
+@pytest.mark.parametrize("layout", ["dense", "ragged"])
+@pytest.mark.parametrize("builder", ["batch", "stream"])
+def test_slot_last_and_seg_steps(builder, layout, graph):
+    """``slot_last`` points at each slot's last cut edge (0 on padded
+    slots); ``seg_steps`` is ceil(log2) of the longest real run over every
+    shard, the padded edge tail left out."""
+    g = _star_graph() if graph == "star" else random_graph(200, 900, seed=8)
+    sh = _build(g, builder, layout, 4)
+    seg = np.asarray(sh.cut_seg)
+    last = np.asarray(sh.slot_last)
+    valid = np.asarray(sh.slot_valid)
+    assert last.shape == (sh.n_parts, sh.n_slots)
+    longest = 1
+    for p in range(sh.n_parts):
+        real = seg[p][seg[p] < sh.n_slots]
+        n_real = int(valid[p].sum())
+        runs = np.bincount(real, minlength=n_real)
+        if len(real):
+            longest = max(longest, int(runs.max()))
+        np.testing.assert_array_equal(last[p, :n_real], np.cumsum(runs) - 1)
+        assert (last[p, n_real:] == 0).all()
+    assert sh.seg_steps == int(np.ceil(np.log2(longest)))
+    if graph == "star":
+        assert sh.seg_steps == 4
+
+
+@pytest.mark.parametrize("nq", [1, 16])
+def test_slot_min_solve_matches_pallas_send(nq):
+    """A sim solve with the XLA send pack gives the distances, rounds and
+    relaxations of the Pallas send kernel (interpret mode); the certificate
+    passes both, and its one relaxation of every edge equals a plain one."""
+    g = random_graph(n=180, m=700, seed=21)
+    sh = build_shards(g, 5)
+    sources = _sources(g, nq)
+    out = {}
+    for sb in BACKENDS:
+        res = SsspEngine.build(sh, SsspConfig(send_backend=sb)).solve(sources)
+        assert res.status == "converged"
+        out[sb] = res
+    a, b = out["xla"], out["pallas"]
+    np.testing.assert_array_equal(a.dist, b.dist)
+    np.testing.assert_array_equal(a.stats.q_rounds, b.stats.q_rounds)
+    np.testing.assert_array_equal(a.stats.q_relaxations,
+                                  b.stats.q_relaxations)
+    # the certificate's relaxation, from a state short of the fixpoint
+    P, blk, n = sh.n_parts, sh.block, g.n_vertices
+    rng = np.random.default_rng(5)
+    dist = np.asarray(a.dist, np.float32) + rng.choice(
+        np.float32([0, 0, 3]), size=a.dist.shape)
+    pad = np.full((nq, P * blk - n), np.inf, np.float32)
+    dist_pk = jnp.asarray(np.concatenate([dist, pad], 1)
+                          .reshape(nq, P, blk).transpose(1, 0, 2))
+    new, payload = jax.vmap(_cert_relax_shard)(sh, dist_pk)
+    merged = jnp.minimum(new, jnp.min(payload, axis=0).transpose(1, 0, 2))
+    got = np.asarray(merged).transpose(1, 0, 2).reshape(nq, -1)[:, :n]
+    m = g.n_edges
+    e_src, e_dst = np.asarray(g.src)[:m], np.asarray(g.dst)[:m]
+    ref = dist.copy()
+    for k in range(nq):
+        np.minimum.at(ref[k], e_dst, dist[k, e_src] + np.asarray(g.weight)[:m])
+    np.testing.assert_array_equal(got, ref)
+    improved = np.asarray(certificate_improved_sim(sh, dist_pk))
+    np.testing.assert_array_equal(improved, (ref < dist).any(axis=1))
 
 
 # ------------------------------------------------ e2e backend matrix ----

@@ -83,8 +83,9 @@ def test_stream_build_equals_batch(graph):
     ragged = build_shards(graph, 4, layout="ragged", **TILE)
     stream = build_shards_stream(edge_chunks_of(graph, chunk_edges=999),
                                  graph.n_vertices, 4, **TILE)
+    assert stream.seg_steps == ragged.seg_steps
     for f in ("loc_src", "loc_dst", "loc_w", "cut_src", "cut_w", "cut_seg",
-              "slot_owner", "slot_dstl", "slot_pos", "recv_idx",
+              "slot_owner", "slot_dstl", "slot_pos", "slot_last", "recv_idx",
               "rx_src", "rx_w", "rx_dstrel", "rx_eid", "rx_ctile",
               "tx_src", "tx_w", "tx_segrel", "tx_eid", "tx_ctile",
               "tx_payload_slot", "mx_pos", "mx_dstrel", "mx_valid",
