@@ -36,6 +36,27 @@ class Batch:
     rounds: int          # the program's ``stats.rounds``
     relaxations: int     # the program's ``stats.relaxations``
     reached_edges: int   # out-edges of the vertices its queries reached
+    compiled: bool = False   # its solve traced a new program
+    counters: dict = dataclasses.field(default_factory=dict)
+    # every scalar field of the program's ``stats`` that is set, by name:
+    # a metric reads a counter with ``counters.get(name)``, so a counter
+    # that a later program adds needs a metric file and no edit here
+
+
+def counters(stats) -> dict:
+    """The scalar fields of a ``SsspStats`` that are not None, as ints.
+    They are on the host once ``solve`` has returned."""
+    return {k: int(v) for k, v in stats._asdict().items()
+            if v is not None and np.ndim(v) == 0}
+
+
+def batch_of(res, real: int, reached_edges: int) -> Batch:
+    """The :class:`Batch` of a ``QueryResult`` (one per batch, or the first
+    of a drained batch's results)."""
+    return Batch(real=real, lanes=res.bucket_k, rounds=int(res.stats.rounds),
+                 relaxations=int(res.stats.relaxations),
+                 reached_edges=reached_edges, compiled=bool(res.compiled),
+                 counters=counters(res.stats))
 
 
 @dataclasses.dataclass
@@ -115,11 +136,9 @@ def closed_loop(engine, traffic: dict, eligible: np.ndarray,
                 res = engine.solve(srcs)
             with TraceAnnotation("bench.check"):
                 reached = np.isfinite(res.dist).astype(np.float32) @ deg
-                w.batches.append(Batch(
-                    real=len(res.sources), lanes=res.bucket_k,
-                    rounds=int(res.stats.rounds),
-                    relaxations=int(res.stats.relaxations),
-                    reached_edges=int(np.sum(reached, dtype=np.float64))))
+                w.batches.append(batch_of(
+                    res, len(res.sources),
+                    int(np.sum(reached, dtype=np.float64))))
                 _count(w, res)
                 sample.offer(res.sources, res.dist)
             w.end = clock()
@@ -154,7 +173,8 @@ def open_loop(engine, traffic: dict, eligible: np.ndarray,
         while i < len(due) or handles:
             now = clock() - t0
             while i < len(due) and due[i] <= now:
-                handles.append((i, engine.submit(int(sources[i]))))
+                with TraceAnnotation("bench.submit"):
+                    handles.append((i, engine.submit(int(sources[i]))))
                 w.lateness_s.append(now - due[i])
                 i += 1
             if not handles:
@@ -197,10 +217,7 @@ def _batches(results, n_batches: int) -> list[tuple[Batch, list]]:
             out[-1][1].real += len(r.sources)
             out[-1][2].append(r)
         else:
-            out.append((key, Batch(real=len(r.sources), lanes=r.bucket_k,
-                                   rounds=int(r.stats.rounds),
-                                   relaxations=int(r.stats.relaxations),
-                                   reached_edges=0), [r]))
+            out.append((key, batch_of(r, len(r.sources), 0), [r]))
     if len(out) != n_batches:
         raise RuntimeError(f"drain ran {n_batches} batches but its results "
                            f"group into {len(out)}")

@@ -23,7 +23,9 @@ The engine wraps its host steps in ``TraceAnnotation`` spans
 names; this module reads the same trace file again with the engine's
 spans and each operation's phase (from the programs' compiled HLO, which
 the profiler stores in the file), and finds the file of a run by its
-``bench.window``.
+``bench.window``. From the same HLO it marks the operations of the body of
+each loop a phase opens (``loop_trips``: the local fixpoint's sweeps), and
+from the spans it times each served query's wait (``queue_waits``).
 
     python3 bench/phases.py <profile dir>   # phase split, gaps, op stats
 """
@@ -36,6 +38,7 @@ import itertools
 import os
 import re
 import sys
+from typing import NamedTuple
 
 if __name__ == "__main__":
     sys.path.insert(0, os.path.dirname(os.path.dirname(
@@ -121,17 +124,46 @@ def _ints(buf, value) -> list:
     return out
 
 
+def phase_loop(op_name: str) -> str | None:
+    """The phase whose scope a ``while`` with this op_name opens directly
+    in: the innermost ``sssp.<name>`` token, with the op_name ending in the
+    one ``while`` that follows it (``jit(sssp_round)/sssp.local/
+    vmap(vmap())/while``). A loop nested in that loop's body, or one that
+    XLA made, opens in none."""
+    found = list(SCOPE.finditer(op_name))
+    if not found or not re.search(r"\bwhile\)*$", op_name):
+        return None
+    rest = op_name[found[-1].end():]
+    return found[-1].group() if len(re.findall(r"\bwhile\b", rest)) == 1 \
+        else None
+
+
+class OpInfo(NamedTuple):
+    """What the compiled HLO says of one instruction."""
+
+    phase: str | None = None
+    inferred: bool = False    # the phase was not read from op_names
+    loop: tuple | None = None
+    # (phase, while): the loop opened directly in a phase's scope
+    # (:func:`phase_loop`) whose body holds the instruction, which so runs
+    # once a trip of that loop
+
+
+NO_INFO = OpInfo()
+
+
 def _hlo_phases(buf, span) -> dict:
-    """{instruction name: (phase, inferred)} of one HloProto, where
+    """{instruction name: :class:`OpInfo`} of one HloProto, where
     ``inferred`` is False for a phase read from op_names (the
     instruction's own, or those of the instructions it calls) and True
     for one taken from data-flow neighbours or a caller: hlo_module = 1;
-    HloModuleProto.computations = 3; HloComputationProto.instructions = 2;
-    HloInstructionProto.name = 1, metadata = 7 (OpMetadata.op_name = 2),
-    id = 35, operand_ids = 36, called_computation_ids = 38; a computation
-    is known by the instruction ids it holds."""
+    HloModuleProto.computations = 3; HloComputationProto.instructions = 2,
+    id = 5; HloInstructionProto.name = 1, opcode = 2, metadata = 7
+    (OpMetadata.op_name = 2), id = 35, operand_ids = 36,
+    called_computation_ids = 38 (a ``while``'s body first, then its
+    condition)."""
     module = next(v for n, v in _fields(buf, *span) if n == 1)
-    name, own, calls, operands, home = {}, {}, {}, {}, {}
+    name, own, calls, operands, home, loops = {}, {}, {}, {}, {}, {}
     for n, comp in _fields(buf, *module):
         if n != 3:
             continue
@@ -139,10 +171,12 @@ def _hlo_phases(buf, span) -> dict:
         for k, v in _fields(buf, *comp):
             if k != 2:
                 continue
-            i, op_name, called, ops, text = None, "", [], [], ""
+            i, op_name, called, ops, text, opcode = None, "", [], [], "", ""
             for f, x in _fields(buf, *v):
                 if f == 1:
                     text = _text(buf, x)
+                elif f == 2:
+                    opcode = _text(buf, x)
                 elif f == 7:
                     op_name = next((_text(buf, y) for g, y in
                                     _fields(buf, *x) if g == 2), "")
@@ -154,6 +188,9 @@ def _hlo_phases(buf, span) -> dict:
                     called.extend(_ints(buf, x))
             name[i], own[i], calls[i], operands[i] = (
                 text, phase_of(op_name), called, ops)
+            opens = phase_loop(op_name) if opcode == "while" else None
+            if opens and called:
+                loops[called[0]] = (opens, text)
             ids.append(i)
         cid = next((v for k, v in _fields(buf, *comp) if k == 5), None)
         home.update((i, cid) for i in ids)
@@ -202,16 +239,15 @@ def _hlo_phases(buf, span) -> dict:
             i = caller.get(home[i])
         return None, False
 
-    return {name[i]: resolve(i) for i in own}
+    return {name[i]: OpInfo(*resolve(i), loops.get(home[i])) for i in own}
 
 
 MODULE_ID = re.compile(r"\((\d+)\)$")
 
 
 def op_phases(path: str) -> dict:
-    """{program id: {instruction name: (phase, inferred)}} of the
-    programs whose HLO
-    the xplane file holds: XSpace.planes = 1; XPlane.name = 2,
+    """{program id: {instruction name: :class:`OpInfo`}} of the programs
+    whose HLO the xplane file holds: XSpace.planes = 1; XPlane.name = 2,
     event_metadata = 4 (map entry: value = 2); XEventMetadata.name = 2,
     stats = 5; XStat.bytes_value = 6."""
     with open(path, "rb") as f:
@@ -272,6 +308,9 @@ class PhaseTrace(trace_mod.Trace):
     # device index -> [phase or None], aligned with ``ops``
     inferred: dict = dataclasses.field(default_factory=dict)
     # device index -> [bool], aligned with ``ops``; absent: none inferred
+    loops: dict = dataclasses.field(default_factory=dict)
+    # device index -> [(program id, phase, while) or None], aligned with
+    # ``ops``: the phase loop whose body holds the operation
 
 
 def load(profile_dir: str) -> PhaseTrace | None:
@@ -297,12 +336,14 @@ def _load_file(path: str, mtime: float) -> PhaseTrace | None:
             for line in lines:
                 if line.name != trace_mod.DEVICE_LINE:
                     continue
-                ops.setdefault(d, []).extend(
-                    (e.start_ns, e.start_ns + e.duration_ns, e.name,
-                     programs.get(_program_at(starts, ids, e.start_ns),
-                                  {}).get(instruction(e.name),
-                                          (None, False)))
-                    for e in line.events)
+                for e in line.events:
+                    prog = _program_at(starts, ids, e.start_ns)
+                    info = programs.get(prog, {}).get(instruction(e.name),
+                                                      NO_INFO)
+                    if info.loop:
+                        info = info._replace(loop=(prog, *info.loop))
+                    ops.setdefault(d, []).append(
+                        (e.start_ns, e.start_ns + e.duration_ns, e.name, info))
             continue
         for line in lines:
             spans.extend((e.name, e.start_ns, e.start_ns + e.duration_ns)
@@ -311,14 +352,15 @@ def _load_file(path: str, mtime: float) -> PhaseTrace | None:
     windows = [(s, e) for name, s, e in spans if name == "bench.window"]
     if not windows:
         return None
-    phases, inferred = {}, {}
+    phases, inferred, loops = {}, {}, {}
     for d, evs in ops.items():
         evs.sort(key=lambda x: x[:3])
-        phases[d] = [p for *_, (p, _) in evs]
-        inferred[d] = [i for *_, (_, i) in evs]
+        phases[d] = [info.phase for *_, info in evs]
+        inferred[d] = [info.inferred for *_, info in evs]
+        loops[d] = [info.loop for *_, info in evs]
         ops[d] = [(s, e, name) for s, e, name, _ in evs]
     return PhaseTrace(ops=ops, spans=spans, window=windows[0], phases=phases,
-                      inferred=inferred)
+                      inferred=inferred, loops=loops)
 
 
 def for_run(run) -> PhaseTrace | None:
@@ -361,6 +403,59 @@ def unscoped_s(trace: PhaseTrace | None) -> float | None:
     busy = None if trace is None else trace_mod.busy_s(trace)
     phased = phase_busy_s(trace, PHASES)
     return None if busy is None or phased is None else busy - phased
+
+
+def loop_trips(trace: PhaseTrace | None, phase: str) -> int | None:
+    """Trips run in the window by the loops opened directly in ``phase``'s
+    scope, on the device that ran most; None where the trace holds no such
+    loop. Every operation of a loop's body runs once a trip, so a loop's
+    trips are the events of the body operation seen most often (an
+    instruction that runs no kernel leaves no event), summed over the
+    loop's runs: under ``vmap`` one trip is a sweep of every lane at once,
+    as many as the longest lane needs."""
+    if trace is None:
+        return None
+    lo, hi = trace.window
+    per = []
+    for d, keys in trace.loops.items():
+        seen = {}
+        for (s, _, text), key in zip(trace.ops[d], keys):
+            if key is not None and key[1] == phase and lo <= s <= hi:
+                op = (key, instruction(text))
+                seen[op] = seen.get(op, 0) + 1
+        trips = {}
+        for (key, _), n in seen.items():
+            trips[key] = max(trips.get(key, 0), n)
+        per.append(sum(trips.values()))
+    return max(per, default=0) or None
+
+
+def queue_waits(trace: PhaseTrace | None, window) -> list | None:
+    """Seconds each query of an open loop's window waited from its due time
+    to the start of the ``sssp.solve`` span of the batch that answered it:
+    its generator lateness (due time to ``submit``, the loop busy in a
+    drain) plus the time from its ``bench.submit`` span to that solve.
+    ``drain`` packs the pending queries into batches in the order they were
+    submitted and solves the batches in turn, so the i-th submit of the
+    window is answered by the batch that holds the i-th query. None where
+    the spans do not match the window's batches."""
+    if trace is None:
+        return None
+    lo, hi = trace.window
+
+    def starts(span):
+        return sorted(s for name, s, _ in trace.spans
+                      if name == span and lo <= s <= hi)
+    submits, solves = starts("bench.submit"), starts("sssp.solve")
+    real = [b.real for b in window.batches]
+    if (not real or len(solves) != len(real)
+            or not len(submits) == sum(real) == len(window.lateness_s)):
+        return None
+    begun = [s for s, k in zip(solves, real) for _ in range(k)]
+    if any(b < q for b, q in zip(begun, submits)):
+        return None
+    return [(b - q) * 1e-9 + late
+            for b, q, late in zip(begun, submits, window.lateness_s)]
 
 
 def ms_per_batch(run, names) -> float | None:
@@ -438,6 +533,7 @@ def describe(profile_dir: str) -> None:
         if e > lo and s < hi:
             counts[name] = counts.get(name, 0) + 1
     in_window = sum(1 for s, e, _ in t.ops[dev] if e > lo and s < hi)
+    print("loop_trips", {name: loop_trips(t, name) for name in PHASES})
     print("spans_in_window", sorted(counts.items()))
     print("device_events_in_window", in_window)
 

@@ -3,10 +3,14 @@
 
     python3 bench/run.py --workload <name> --seed <n> --seconds <s> --trace <0|1>
 
-The cell is looked up in ``BENCHMARK.json``; its configuration, traffic and
-metrics are read from ``bench/configs/<config>.json``,
-``bench/traffic/<traffic>.json`` and ``bench/metrics/<metric>.py``, so a new
-cell needs new files and a ``workloads`` entry, and no edit here.
+The cell is looked up in ``BENCHMARK.json``; its configuration, traffic,
+graph generator and metrics are read from ``bench/configs/<config>.json``,
+``bench/traffic/<traffic>.json``, ``bench/generators/<generator>.py`` (the
+configuration's ``generator``) and ``bench/metrics/<metric>.py``. So a new
+cell, graph family or metric needs new files and new ``BENCHMARK.json``
+entries, and no edit here; a metric reads the program's counters of each
+batch by name (``Batch.counters``), so a counter the program adds needs
+only a new metric file.
 
 A run generates the graph from the seed, builds the program's shards,
 compiles and warms up the cell's batch shapes (all of that is ``setup_s``),
@@ -26,7 +30,6 @@ T_PROCESS = time.perf_counter()
 
 import argparse  # noqa: E402
 import dataclasses  # noqa: E402
-import importlib.util  # noqa: E402
 import json  # noqa: E402
 import math  # noqa: E402
 import os  # noqa: E402
@@ -34,12 +37,14 @@ import shutil  # noqa: E402
 import sys  # noqa: E402
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-BENCH = os.path.join(ROOT, "bench")
 for _p in (os.path.join(ROOT, "src"), ROOT):
     if _p not in sys.path:
         sys.path.insert(0, _p)
 
 import numpy as np  # noqa: E402
+
+from bench import by_name, load_module  # noqa: E402
+from bench.stats import peaks  # noqa: E402
 
 
 def log(*parts) -> None:
@@ -53,24 +58,9 @@ def load_json(path: str) -> dict:
         return json.load(f)
 
 
-def by_name(kind: str, name: str, suffix: str) -> str:
-    """Path of ``bench/<kind>/<name><suffix>``; a clear error if absent."""
-    path = os.path.join(BENCH, kind, name + suffix)
-    if not os.path.isfile(path):
-        have = sorted(f[: -len(suffix)] for f in os.listdir(
-            os.path.join(BENCH, kind)) if f.endswith(suffix))
-        raise LookupError(f"no {kind} named {name!r} (bench/{kind}/{name}"
-                          f"{suffix}); have {have}")
-    return path
-
-
 def load_metric(name: str):
     """The ``read(run)`` of ``bench/metrics/<name>.py``."""
-    spec = importlib.util.spec_from_file_location(
-        f"bench.metrics.{name}", by_name("metrics", name, ".py"))
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read
+    return load_module("metrics", name).read
 
 
 @dataclasses.dataclass
@@ -117,14 +107,6 @@ def require_tpu(chips: int):
     return devices
 
 
-def peaks(device_kind: str) -> dict:
-    table = load_json(os.path.join(BENCH, "peaks.json"))["devices"]
-    if device_kind not in table:
-        raise LookupError(f"device kind {device_kind!r} is not in "
-                          f"bench/peaks.json; have {sorted(table)}")
-    return table[device_kind]
-
-
 def enable_compile_cache() -> str:
     """The program's compilation cache (``JAX_COMPILATION_CACHE_DIR``, or
     the fixed ``.jax_cache`` in the checkout), keeping every program, so
@@ -146,6 +128,7 @@ class Run:
     setup_s: float
     window: object       # loads.Window
     trace: object        # trace.Trace, or None
+    device_kind: str | None = None   # JAX's, where bench/peaks.json has it
 
 
 def build(cell: Cell, seed: int):
@@ -230,9 +213,11 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, devices,
             f"max={max(window.lateness_s)} "
             f"backlog_at_close={window.backlog_at_close}")
     log(f"window_s={window.end - window.start} batches={len(window.batches)} "
-        f"queries={window.attempted}")
+        f"queries={window.attempted} "
+        f"window_compiles={sum(b.compiled for b in window.batches)}")
     reduced = trace_mod.load(trace_dir) if trace else None
-    run = Run(setup_s=setup_s, window=window, trace=reduced)
+    kind = dev.device_kind if dev.platform == "tpu" else None
+    run = Run(setup_s=setup_s, window=window, trace=reduced, device_kind=kind)
     metrics = {}
     for m in (cell.per_layer if trace else cell.end_to_end):
         value = load_metric(m["name"])(run)

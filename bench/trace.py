@@ -1,8 +1,9 @@
 """Reduction of a profiler trace to the per-layer metrics and the breakdown.
 
 The harness wraps its window in ``bench.window`` and its calls into the
-program in ``bench.solve``, ``bench.drain``, ``bench.wait_arrival`` and
-``bench.check`` (``jax.profiler.TraceAnnotation``), so they land in the
+program in ``bench.solve``, ``bench.submit``, ``bench.drain``,
+``bench.wait_arrival`` and ``bench.check``
+(``jax.profiler.TraceAnnotation``), so they land in the
 trace on the same clock as the device's operations. Device operations are
 the events of the ``XLA Ops`` line of each ``/device:TPU:<i>`` plane.
 
@@ -20,8 +21,8 @@ import numpy as np
 
 DEVICE_PLANE = re.compile(r"^/device:TPU:(\d+)$")
 DEVICE_LINE = "XLA Ops"
-SPANS = ("bench.window", "bench.solve", "bench.drain", "bench.wait_arrival",
-         "bench.check")
+SPANS = ("bench.window", "bench.solve", "bench.submit", "bench.drain",
+         "bench.wait_arrival", "bench.check")
 TOP = 10
 
 

@@ -11,20 +11,18 @@ for _p in (os.path.join(ROOT, "src"), ROOT):
     if _p not in sys.path:
         sys.path.insert(0, _p)
 
-from bench import run  # noqa: E402
+from bench import graphs, run  # noqa: E402
 
 SEED = 2**31 + 77
 
 
 def tiny_cell(name: str) -> run.Cell:
     """The cell ``name`` as ``BENCHMARK.json`` defines it, with its graph
-    cut to at most 2**10 vertices and its arrival rate to 20 queries/s."""
+    cut by its generator's ``tiny`` to at most 2**10 vertices and its
+    arrival rate to 20 queries/s."""
     cell = run.load_cell(name)
-    g = cell.config["graph"]
-    g["scale"] = min(g["scale"], 10)
-    if "vertices" in g:
-        g["vertices"] = min(g["vertices"], 3 << (g["scale"] - 2))
-        g["edges"] = min(g["edges"], 3 * g["vertices"])
+    cell.config["graph"] = graphs.generator(
+        cell.config["generator"]).tiny(cell.config["graph"])
     cell.config["engine"]["sssp_config"] = {"max_rounds": 64}
     if cell.traffic["loop"] == "open":
         cell.traffic["rate_qps"] = 20.0

@@ -3,6 +3,7 @@ the window's close, the trace reduction, lookups by name, the reference,
 and the refusal to run anywhere but a TPU."""
 from __future__ import annotations
 
+import hashlib
 import json
 import math
 import os
@@ -10,6 +11,7 @@ import shutil
 import subprocess
 import sys
 import types
+from typing import NamedTuple
 
 import numpy as np
 import pytest
@@ -47,6 +49,13 @@ class FakeClock:
         return self.t
 
 
+class FakeStats(NamedTuple):
+    rounds: int
+    relaxations: int
+    msgs_sent: int
+    q_rounds: np.ndarray = None
+
+
 class FakeEngine:
     """Answers each query with the reference's row; every solve or drain
     takes ``cost`` seconds of the fake clock."""
@@ -69,7 +78,9 @@ class FakeEngine:
             dist=np.stack([reference.shortest_paths(self.g, s)
                            for s in srcs]).astype(np.float32),
             q_converged=np.array([int(s) not in self.bad for s in srcs]),
-            stats=types.SimpleNamespace(rounds=3, relaxations=7 * k))
+            compiled=False,
+            stats=FakeStats(rounds=3, relaxations=7 * k, msgs_sent=5 * k,
+                            q_rounds=np.full(k, 3)))
 
     def submit(self, s):
         self.pending.append(s)
@@ -83,6 +94,7 @@ class FakeEngine:
             for i, s in enumerate(batch):
                 out.append(types.SimpleNamespace(
                     sources=(s,), bucket_k=res.bucket_k, wall_s=res.wall_s,
+                    compiled=res.compiled,
                     dist=res.dist[i:i + 1],
                     q_converged=res.q_converged[i:i + 1], stats=res.stats))
         return out
@@ -240,7 +252,7 @@ def test_trace_without_device_reads_nothing(tmp_path):
 def test_reference_agrees_with_scipy_dijkstra():
     sp = pytest.importorskip("scipy.sparse")
     csgraph = pytest.importorskip("scipy.sparse.csgraph")
-    n, chunks = graphs.rmat(3, scale=9, edge_factor=4)
+    n, chunks = graphs.generator("rmat").generate(3, scale=9, edge_factor=4)
     g = graphs.simple_graph(n, chunks)
     src = np.repeat(np.arange(g.n), g.out_degree)
     a = sp.csr_matrix((g.w.astype(np.float64), (src, g.dst)), (g.n, g.n))
@@ -266,11 +278,11 @@ def test_compare_reports_each_number_beside_its_limit():
 
 
 def test_rmat_topology_is_fixed_and_seed_draws_weights():
-    a = graphs.rmat(2**31 + 3, scale=8, edges=500, vertices=200)
-    b = graphs.rmat(2**31 + 3, scale=8, edges=500, vertices=200)
-    c = graphs.rmat(2**31 + 4, scale=8, edges=500, vertices=200)
-    d = graphs.rmat(2**31 + 3, scale=8, edges=500, vertices=200,
-                    topology_seed=5)
+    rmat = graphs.generator("rmat").generate
+    a = rmat(2**31 + 3, scale=8, edges=500, vertices=200)
+    b = rmat(2**31 + 3, scale=8, edges=500, vertices=200)
+    c = rmat(2**31 + 4, scale=8, edges=500, vertices=200)
+    d = rmat(2**31 + 3, scale=8, edges=500, vertices=200, topology_seed=5)
     assert a[0] == 200
     for x, y in zip(a[1][0], b[1][0]):
         np.testing.assert_array_equal(x, y)
@@ -281,6 +293,92 @@ def test_rmat_topology_is_fixed_and_seed_draws_weights():
     src, dst, w = a[1][0]
     assert src.max() < 200 and np.all(src != dst)
     assert np.all((w >= 1.0) & (w < 20.0))
+
+
+def _digest(n, chunks) -> str:
+    h = hashlib.sha256(np.int64(n).tobytes())
+    for chunk in chunks:
+        for a in chunk:
+            h.update(str(a.dtype).encode())
+            h.update(np.ascontiguousarray(a).tobytes())
+    return h.hexdigest()
+
+
+# sha256 of (n, then each chunk's sources, heads and weights with their
+# dtypes) as the R-MAT generator gave them before it moved to
+# bench/generators/rmat.py (bench/graphs.py:rmat), for seed 2**31 + 77
+RMAT_DIGESTS = {
+    ("graph500-s18", "tiny"): (1024, 32498, "d2cfe37f6e3afed29d080be1f45d242316ccd5ee42a3fe0a4e03d98a89cdabc8"),
+    ("graph500-s18", "full"): (262144, 8387142, "f615a1ce22c90dd03e8f6b62c3f9dfb6714170bdfd53201deccc8a2ef6f3e935"),
+    ("paper-graph1", "tiny"): (768, 4568, "ffc242fefc1694b720677bc8d10a0cb7917771dab3e1ffc69d9a218703acf962"),
+    ("paper-graph1", "full"): (391529, 1747330, "3a8d8bf9b41046e173e311abcb52b47e26f6b0fcd6c5134207d2b1745ef17291"),
+}
+
+
+@pytest.mark.parametrize("config,cut", sorted(RMAT_DIGESTS))
+def test_rmat_graphs_are_bit_identical_to_before_the_move(config, cut):
+    with open(os.path.join(ROOT, "bench", "configs", config + ".json")) as f:
+        cfg = json.load(f)
+    gen = graphs.generator(cfg["generator"])
+    if cut == "tiny":
+        cfg["graph"] = gen.tiny(cfg["graph"])
+    n, chunks = graphs.generate(cfg, 2**31 + 77)
+    assert (n, sum(len(c[0]) for c in chunks), _digest(n, chunks)) == \
+        RMAT_DIGESTS[config, cut]
+
+
+def test_generator_tiny_cuts_to_a_rehearsal_size():
+    tiny = graphs.generator("rmat").tiny
+    g = {"scale": 19, "edges": 873775, "vertices": 391529, "a": 0.57}
+    assert tiny(g) == {"scale": 10, "edges": 2304, "vertices": 768,
+                       "a": 0.57}
+    assert g["scale"] == 19             # a copy, the configuration kept
+    assert tiny({"scale": 8, "edge_factor": 16}) == {"scale": 8,
+                                                     "edge_factor": 16}
+
+
+def test_batches_carry_the_programs_scalar_counters(monkeypatch):
+    g = two_components()
+    clock = FakeClock()
+    monkeypatch.setattr(loads, "perf_counter", clock)
+    w = loads.closed_loop(FakeEngine(g, clock), {"batch": 2},
+                          np.flatnonzero(g.out_degree), g.out_degree, 1.5, 1,
+                          loads.Reservoir(1, loads.rng_for(1, 3)))
+    assert len(w.batches) == 2
+    for b in w.batches:
+        # every scalar field that is set, by name; not the per-query arrays
+        assert b.counters == {"rounds": 3, "relaxations": 14, "msgs_sent": 10}
+        assert (b.rounds, b.relaxations, b.compiled) == (3, 14, False)
+
+
+def test_local_floor_bytes_by_hand():
+    # 8 bytes a lane (tail's distance read, head's min-written), 12 of
+    # edge (tail, head, weight) shared by the lanes
+    assert stats.local_floor_bytes(1000, 16) == 8750.0
+    assert stats.local_floor_bytes(3, 1) == 60.0
+    assert stats.local_floor_bytes(0, 16) == 0.0
+
+
+def test_local_roofline_by_hand(monkeypatch):
+    from bench import phases
+    t = phases.PhaseTrace(ops={0: [(0, 5e8, "%fusion.1 = f32[8] fusion()"),
+                                   (6e8, 7e8, "%fusion.2 = f32[8] fusion()")]},
+                          spans=[], window=(0, 1e9),
+                          phases={0: ["sssp.local", "sssp.send"]})
+    monkeypatch.setattr(phases, "for_run", lambda r: t)
+    window = loads.Window(batches=[
+        loads.Batch(real=16, lanes=16, rounds=3, relaxations=10**9,
+                    reached_edges=1),
+        loads.Batch(real=3, lanes=4, rounds=3, relaxations=5 * 10**8,
+                    reached_edges=1)])
+    read = run.load_metric("local_roofline.batch")
+    # (1e9 * 8.75 + 5e8 * 11) bytes in 0.5 s under sssp.local at 819 GB/s
+    got = read(run.Run(1.0, window, t, device_kind="TPU v5 lite"))
+    assert got == pytest.approx(100 * 14.25e9 / (0.5 * 819e9))
+    assert got == pytest.approx(3.47985347985348)
+    assert read(run.Run(1.0, window, t, device_kind=None)) is None
+    t.phases[0][0] = "sssp.send"       # no time under sssp.local
+    assert read(run.Run(1.0, window, t, device_kind="TPU v5 lite")) is None
 
 
 def test_open_loop_brings_the_same_arrivals_in_the_seeds_order():
@@ -338,7 +436,7 @@ def test_unknown_names_fail_clearly():
         run.by_name("traffic", "nope", ".json")
     with pytest.raises(LookupError, match="not in bench/peaks.json"):
         run.peaks("TPU v0 imaginary")
-    with pytest.raises(KeyError, match="unknown generator"):
+    with pytest.raises(LookupError, match="no generators named 'nope'"):
         graphs.generate({"generator": "nope", "graph": {}}, 1)
     assert run.peaks("TPU v5 lite")["hbm_bytes_per_s"] == 819e9
 

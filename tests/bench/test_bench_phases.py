@@ -3,6 +3,8 @@ trace whose operations carry scopes and whose spans nest ``sssp.sync``
 inside ``bench.solve``, and on a traced tiny run of the harness."""
 from __future__ import annotations
 
+import dataclasses
+
 import pytest
 from bench_tiny import run_tiny, tiny_cell
 
@@ -169,6 +171,20 @@ def _field(num: int, payload) -> bytes:
     return _varint(num << 3 | 2) + _varint(len(payload)) + payload
 
 
+def _xplane_of(compiled, path, program: int = 42) -> str:
+    """A trace file that holds only ``compiled``'s optimized HLO, as the
+    profiler stores it: an ``Hlo Proto`` stat in ``/host:metadata``."""
+    module = compiled.runtime_executable().hlo_modules()[0]
+    stat = _field(1, 7) + _field(6, _field(
+        1, module.as_serialized_hlo_module_proto()))
+    meta = (_field(1, 3) + _field(2, f"jit_f({program})".encode())
+            + _field(5, stat))
+    plane = (_field(1, 5) + _field(2, b"/host:metadata")
+             + _field(4, _field(1, 3) + _field(2, meta)))
+    path.write_bytes(_field(1, plane))
+    return str(path)
+
+
 def test_op_phases_read_the_compiled_hlo_in_the_trace_file(tmp_path):
     """The phases of a program's instructions, from its optimized HLO as
     the profiler stores it (an ``Hlo Proto`` stat in ``/host:metadata``):
@@ -187,15 +203,7 @@ def test_op_phases_read_the_compiled_hlo_in_the_trace_file(tmp_path):
             return jnp.cumsum(y) + 1
 
     compiled = jax.jit(f).lower(jnp.ones(64)).compile()
-    module = compiled.runtime_executable().hlo_modules()[0]
-    stat = _field(1, 7) + _field(6, _field(
-        1, module.as_serialized_hlo_module_proto()))
-    meta = _field(1, 3) + _field(2, b"jit_f(42)") + _field(5, stat)
-    plane = (_field(1, 5) + _field(2, b"/host:metadata")
-             + _field(4, _field(1, 3) + _field(2, meta)))
-    path = tmp_path / "t.xplane.pb"
-    path.write_bytes(_field(1, plane))
-    got = phases.op_phases(str(path))
+    got = phases.op_phases(_xplane_of(compiled, tmp_path / "t.xplane.pb"))
     assert list(got) == [42]
     table = got[42]
     text = compiled.as_text()
@@ -218,5 +226,141 @@ def test_op_phases_read_the_compiled_hlo_in_the_trace_file(tmp_path):
             assert table[n][1] == ("sssp.send" not in line), line
     # XLA's own ops between two phases (the cumsum's reduce-window, whose
     # op_name lost the scope) may stay unscoped, never take a third phase
-    assert {p for p, _ in table.values()} <= {"sssp.local", "sssp.send",
-                                               None}
+    assert {v.phase for v in table.values()} <= {"sssp.local", "sssp.send",
+                                                  None}
+
+
+@pytest.mark.parametrize("op_name,opens", [
+    ("jit(sssp_round)/sssp.local/vmap(vmap())/while", "sssp.local"),
+    ("jit(f)/vmap(sssp.local)/while", "sssp.local"),
+    ("jit(f)/shard_map/while/body/sssp.local/vmap(while)", "sssp.local"),
+    ("jit(f)/sssp.local/while/body/while", None),     # nested in the loop
+    ("jit(f)/sssp.local/while/body/mul", None),       # in the body
+    ("jit(f)/shard_map/while", None),                 # in no phase
+    ("", None),                                       # XLA's own loop
+])
+def test_phase_loop_is_the_while_opened_directly_in_a_scope(op_name, opens):
+    assert phases.phase_loop(op_name) == opens
+
+
+def _body(text: str, loop: str) -> list:
+    """Instruction names of the body computation of ``%<loop>``."""
+    line = next(x for x in text.splitlines() if f"%{loop} = " in x)
+    body = line.split("body=%", 1)[1].split(",", 1)[0].split()[0]
+    comp = text.split(f"%{body} ", 1)[1].split("\n}", 1)[0]
+    return [x.split(" = ", 1)[0].split("%")[-1].strip()
+            for x in comp.splitlines()[1:] if " = " in x]
+
+
+def test_op_phases_mark_the_body_of_the_phase_loop(tmp_path):
+    """Each instruction of the body of the ``while`` opened in
+    ``sssp.local`` runs once a trip and is marked with that loop; those
+    of its condition, of a loop nested in its body and outside it are
+    not."""
+    import jax
+    import jax.numpy as jnp
+
+    def inner(c):
+        return jax.lax.while_loop(lambda d: d[1][0] < 50.0,
+                                  lambda d: (d[0] + 1, d[1] * 3.0 + 1.0),
+                                  (0, c[1]))[1]
+
+    def f(x):
+        with jax.named_scope("sssp.local"):
+            y = jax.lax.while_loop(
+                lambda c: c[1][0] < 1e6,
+                lambda c: (c[0] + 1, jnp.sin(inner(c)) + c[1] * 2.0),
+                (0, x))[1]
+        with jax.named_scope("sssp.send"):
+            return jnp.cumsum(y) + 1
+
+    compiled = jax.jit(f).lower(jnp.ones(64)).compile()
+    table = phases.op_phases(_xplane_of(compiled, tmp_path / "t.xplane.pb"))[42]
+    text = compiled.as_text()
+    loops = {v.loop for v in table.values()} - {None}
+    assert len(loops) == 1
+    ((phase, outer),) = loops
+    assert phase == "sssp.local" and outer.startswith("while")
+    marked = sorted(n for n, v in table.items() if v.loop)
+    assert marked == sorted(_body(text, outer))
+    # the loop nested in the body marks none of its own body's
+    whiles = [x.split(" = ", 1)[0].split("%")[-1] for x in text.splitlines()
+              if " while(" in x]
+    nested = [w for w in whiles if w != outer]
+    assert nested
+    for w in nested:
+        assert not any(table[n].loop for n in _body(text, w))
+
+
+def test_loop_trips_count_the_body_op_seen_most():
+    W = (0, 1000)
+    local_a, local_b = (1, "sssp.local", "while.5"), (2, "sssp.local",
+                                                      "while.9")
+    send = (1, "sssp.send", "while.11")
+    evs = ([(t, t + 5, "%fusion.6 = f32[8] fusion()", local_a)
+            for t in (-5, 10, 20, 30, 40)]          # the first before the window
+           + [(t + 1, t + 3, "%fusion.7 = f32[8] fusion()", local_a)
+              for t in (10, 20, 30, 40)]
+           + [(t, t + 5, "%fusion.3 = f32[8] fusion()", local_b)
+              for t in (100, 200)]
+           + [(t, t + 5, "%fusion.12 = f32[8] fusion()", send)
+              for t in range(300, 370, 10)]
+           + [(500, 600, "%while.5 = (s32[]) while()", None)])
+    evs.sort()
+
+    def trace(per_device):
+        return phases.PhaseTrace(
+            ops={d: [e[:3] for e in es] for d, es in per_device.items()},
+            spans=[], window=W,
+            phases={d: [None] * len(es) for d, es in per_device.items()},
+            loops={d: [e[3] for e in es] for d, es in per_device.items()})
+
+    t = trace({0: evs, 1: evs[:3]})
+    # 4 trips of while.5 in the window and 2 of while.9; the device that
+    # ran most; the send loop is another phase's
+    assert phases.loop_trips(t, "sssp.local") == 6
+    assert phases.loop_trips(t, "sssp.send") == 7
+    assert phases.loop_trips(t, "sssp.merge") is None
+    assert phases.loop_trips(None, "sssp.local") is None
+    assert phases.loop_trips(synthetic(), "sssp.local") is None
+
+
+def test_sweeps_per_round_over_the_windows_rounds(monkeypatch):
+    t = synthetic()
+    monkeypatch.setattr(phases, "loop_trips",
+                        lambda tr, phase: 12 if phase == "sssp.local" else None)
+    monkeypatch.setattr(phases, "for_run", lambda r: t)
+    batches = [loads.Batch(real=16, lanes=16, rounds=r, relaxations=1,
+                           reached_edges=1) for r in (2, 1)]
+    read = run.load_metric("sweeps_per_round.batch")
+    assert read(run.Run(1.0, loads.Window(batches=batches), t)) == 4.0
+    assert read(run.Run(1.0, loads.Window(), t)) is None
+    monkeypatch.setattr(phases, "loop_trips", lambda tr, phase: None)
+    assert read(run.Run(1.0, loads.Window(batches=batches), t)) is None
+
+
+def test_queue_waits_from_due_time_to_the_batchs_solve(monkeypatch):
+    spans = [("bench.window", 0, 1000), ("bench.submit", 100, 101),
+             ("bench.submit", 150, 151), ("bench.submit", 300, 301),
+             ("bench.drain", 190, 700), ("sssp.solve", 200, 290),
+             ("sssp.solve", 400, 690)]
+    t = phases.PhaseTrace(ops={}, spans=spans, window=(0, 1000))
+    batches = [loads.Batch(real=k, lanes=k, rounds=1, relaxations=1,
+                           reached_edges=0) for k in (2, 1)]
+    # due times 20 ns, 0 and 50 ns before each submit
+    w = loads.Window(batches=batches, lateness_s=[20e-9, 0.0, 50e-9])
+    waits = phases.queue_waits(t, w)
+    assert waits == pytest.approx([120e-9, 50e-9, 150e-9])
+    monkeypatch.setattr(phases, "for_run", lambda r: t)
+    read = run.load_metric("queue_wait_p85_ms.serve")
+    assert read(run.Run(1.0, w, t)) == pytest.approx(150e-6)
+    # spans that do not match the window's batches read nothing
+    for other in ([batches[0]], [batches[0], batches[0]]):
+        assert phases.queue_waits(t, dataclasses.replace(
+            w, batches=other)) is None
+    assert phases.queue_waits(t, dataclasses.replace(
+        w, lateness_s=[0.0, 0.0])) is None
+    assert phases.queue_waits(None, w) is None
+    # a batch that began before one of its queries was submitted
+    t.spans[-1] = ("sssp.solve", 250, 690)
+    assert phases.queue_waits(t, w) is None
